@@ -1,7 +1,11 @@
 """Paper §III serving tables: image throughput of the GxM inference path
 for ResNet-50 and Inception — images/sec vs batch size and device count,
 with efficiency relative to the three-term roofline model
-(``launch/roofline.py``).
+(``launch/roofline.py``, priced for its ``MODEL_TARGET`` chip).
+
+A CPU-only table: every worker is pinned to ``JAX_PLATFORMS=cpu`` and every
+row says ``platform: cpu``.  Its times are XLA-on-CPU times; it is not a
+chip measurement and must not be run or read as one.
 
 Each device count runs in a fresh subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set before jax
@@ -66,6 +70,8 @@ def _worker(args) -> None:
         roof_ips = batch / roof.step_time_s if roof.step_time_s else 0.0
         measured_ips = batch / (us / 1e6)
         rows.append({
+            "platform": jax.devices()[0].platform,
+            "roofline_target": rl.MODEL_TARGET,
             "arch": args.arch, "devices": ndev, "batch": batch,
             "image": image, "us_per_batch": round(us, 1),
             "images_per_s": round(measured_ips, 2),
@@ -131,6 +137,7 @@ def main(argv=None) -> None:
             for r in rows:
                 emit(f"serve_{arch}_d{devices}_b{r['batch']}",
                      r["us_per_batch"],
+                     f"platform={r['platform']};"
                      f"images_per_s={r['images_per_s']};"
                      f"roofline_eff={r['roofline_efficiency']};"
                      f"dominant={r['roofline_dominant']}")

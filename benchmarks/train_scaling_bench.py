@@ -3,9 +3,10 @@
 wire format), the training sibling of ``serve_cnn_bench``.
 
 Writes ``BENCH_train_scaling.json`` at the repo root.  The table is the
-schedule-resolved *model* (same v5e roofline constants as
-``benchmarks/scaling_bench.py``), so the file is reproducible on any host
-and later PRs can diff it:
+schedule-resolved *model* of a TPU v5e (``launch.roofline.MODEL_TARGET``,
+the same constants as ``benchmarks/scaling_bench.py``), computed on the
+CPU — every row says ``platform: cpu``; it is not a chip measurement.  So
+the file is reproducible on any host and later PRs can diff it:
 
   t_comp     = local_batch · 3·4.1 GFLOP / (peak · kernel_eff)
   t_allreduce= ring all-reduce of the 25.6M-param gradient at the wire
@@ -74,6 +75,7 @@ def build_report() -> dict:
             ips = devices * LOCAL_BATCH / t
             no_overlap_ips = devices * LOCAL_BATCH / (t_comp + t_ar)
             rows.append({
+                "platform": "cpu",
                 "devices": devices,
                 "reduction": reduction,
                 "images_per_s": round(ips, 1),
@@ -87,7 +89,9 @@ def build_report() -> dict:
                     RESNET50_PARAMS * BYTES_PER_PARAM[reduction])
                 if devices > 1 else 0,
             })
+    from repro.launch.roofline import MODEL_TARGET
     return {
+        "modeled_target": MODEL_TARGET,
         "model": "resnet50",
         "local_batch": LOCAL_BATCH,
         "gflop_per_image": RESNET50_GFLOP,
@@ -134,7 +138,8 @@ def _worker(args) -> None:
         loss = float(metrics["loss"])
         assert np.isfinite(loss), (reduction, loss)
         us = time_call(step, state, batch, warmup=1, iters=3)
-        rows.append({"devices": ndev, "reduction": reduction,
+        rows.append({"platform": jax.devices()[0].platform,
+                     "devices": ndev, "reduction": reduction,
                      "global_batch": n, "loss": round(loss, 4),
                      "us_per_step": round(us, 1),
                      "images_per_s": round(n / (us / 1e6), 2)})
@@ -183,6 +188,7 @@ def main(argv=None) -> None:
     out_path.write_text(json.dumps(report, indent=1, sort_keys=True) + "\n")
     for r in report["rows"]:
         emit(f"train_scaling_model_n{r['devices']:02d}_{r['reduction']}", 0.0,
+             f"platform={r['platform']};"
              f"imgs_per_s={r['images_per_s']};"
              f"eff={r['scaling_efficiency']};"
              f"no_overlap_eff={r['no_overlap_efficiency']}")
@@ -202,6 +208,7 @@ def main(argv=None) -> None:
                 measured.append(r)
                 emit(f"train_scaling_live_d{r['devices']}_{r['reduction']}",
                      r["us_per_step"],
+                     f"platform={r['platform']};"
                      f"images_per_s={r['images_per_s']};loss={r['loss']}")
     print("RESULT " + json.dumps({**report, "measured": measured}))
 

@@ -51,7 +51,10 @@ def main():
 
     from repro.graph import GxM, resnet50
     from repro.graph.etg import build_etg
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.train.step import make_cnn_train_step, warmup_cnn_train
+
+    enable_compile_cache()
 
     stages = (3, 4, 6, 3) if args.full else (1, 1, 1, 1)
     nl = resnet50(num_classes=10, stages=stages)
@@ -60,7 +63,7 @@ def main():
           f"{etg.stats['nodes_after']} tasks after fusion; "
           f"{len(etg.kernel_cache)} distinct JIT conv kernels")
 
-    m = GxM(nl, impl="xla", num_classes=10)
+    m = GxM(nl, num_classes=10)     # default backend: pallas on a TPU
     params = m.init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     assert args.batch % args.devices == 0, (args.batch, args.devices)

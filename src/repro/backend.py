@@ -5,6 +5,11 @@
 "xla"       — pure-jnp/lax reference path (CPU dry-run lowering at 512 devices
               and the numerics oracle)
 
+With ``REPRO_BACKEND`` unset the default is resolved on first use, never at
+import: "pallas" where ``jax.default_backend() == "tpu"``, "xla" elsewhere.
+Asking for "pallas" on a host without a TPU raises instead of running the
+kernels somewhere they were not built for.
+
 The per-shape JIT specialization story of the paper (§II-D) is carried by
 jax.jit itself: every (layer shape × blocking) pair traces and compiles its
 own specialized kernel, on demand, cached — libxsmm's runtime code
@@ -65,7 +70,7 @@ _VALID_BWD_DUALITY = ("phase", "dilate")
 _VALID_GRAD_COMPRESS = ("off", "int8")
 _VALID_QUANTIZE = ("off", "int8")
 _VALID_CHAIN_FUSION = ("off", "on")
-_backend = os.environ.get("REPRO_BACKEND", "xla")
+_backend = os.environ.get("REPRO_BACKEND")     # None: resolve on first use
 _autotune = os.environ.get("REPRO_AUTOTUNE", "off")
 _conv_tiling = os.environ.get("REPRO_CONV_TILING", "tiled")
 _bwd_duality = os.environ.get("REPRO_BWD_DUALITY", "phase")
@@ -110,14 +115,31 @@ if _conv_tiling not in _VALID_CONV_TILING:
     _conv_tiling = "tiled"
 
 
+def _check_backend(name: str) -> str:
+    import jax
+    if name not in _VALID:
+        raise ValueError(f"unknown kernel backend {name!r} (valid: "
+                         f"{', '.join(_VALID)})")
+    if name == "pallas" and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"kernel backend 'pallas' needs a TPU, but JAX runs on "
+            f"{jax.default_backend()!r}; use 'interpret' or 'xla' there")
+    return name
+
+
 def get_backend() -> str:
-    return _backend
+    """The process-wide kernel backend, resolving the default on first use:
+    "pallas" on a TPU, "xla" elsewhere (``REPRO_BACKEND`` overrides)."""
+    global _backend
+    if _backend is None:
+        import jax
+        _backend = "pallas" if jax.default_backend() == "tpu" else "xla"
+    return _check_backend(_backend)
 
 
 def set_backend(name: str) -> None:
     global _backend
-    assert name in _VALID, name
-    _backend = name
+    _backend = _check_backend(name)
 
 
 @contextmanager
@@ -132,9 +154,7 @@ def use_backend(name: str):
 
 
 def resolve(impl: str | None) -> str:
-    impl = impl or _backend
-    assert impl in _VALID, impl
-    return impl
+    return _check_backend(impl) if impl else get_backend()
 
 
 def get_autotune() -> str:

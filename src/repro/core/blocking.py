@@ -35,7 +35,6 @@ import os
 # budget (CI exercises the tiled kernel under pressure with it)
 VMEM_BUDGET = int(os.environ.get("REPRO_VMEM_BUDGET", 16 * 1024 * 1024))
 LANE = 128
-SUBLANE = 8
 MXU = 128
 
 
@@ -47,6 +46,8 @@ class ConvBlocking:
     order: str         # grid/dryrun loop order (paper §II-C)
     vmem_bytes: int    # modeled working set
     rb_q: int = 0      # output cols per microkernel (paper RB_Q; 0 = full Q)
+                       # — priced by the VMEM/traffic models only: the tiled
+                       # kernels always compute full rows
 
 
 def divisors(x: int):
@@ -54,14 +55,11 @@ def divisors(x: int):
 
 
 def aligned_block(dim: int) -> int:
-    """Largest sublane-aligned divisor of `dim` within one MXU lane tile —
-    the feature-block choice that every kernel's `dim % blk == 0` assert
-    accepts (non-power-of-two dims like Inception's 192 included)."""
-    # downward over sublane multiples: <= 16 iterations, this runs per dispatch
-    for d in range(min(dim, LANE) - min(dim, LANE) % SUBLANE, 0, -SUBLANE):
-        if dim % d == 0:
-            return d
-    return min(dim, LANE)
+    """Feature block (C or K) within one MXU lane tile that Mosaic accepts:
+    a block on the lane dim must be a multiple of 128 or the whole dim.  So
+    128 where it divides ``dim``, else the whole dim (Inception's 192
+    included)."""
+    return LANE if dim % LANE == 0 else dim
 
 
 def conv_working_set(*, h: int, w: int, c: int, k_blk: int, r: int, s: int,

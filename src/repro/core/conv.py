@@ -60,7 +60,7 @@ def conv2d_fwd(x, w, *, stride=1, padding=1, bias=None, scale=None,
     return conv2d_direct(x, w, stride=stride, padding=padding, bias=bias,
                          scale=scale, shift=shift, residual=residual,
                          relu=relu, rb_p=blk.rb_p, k_blk=blk.k_blk,
-                         c_blk=blk.c_blk, rb_q=blk.rb_q, order=blk.order,
+                         c_blk=blk.c_blk, order=blk.order,
                          interpret=(impl == "interpret"))
 
 
@@ -114,7 +114,7 @@ def conv2d_q8_fwd(x, w_q, *, x_scale, w_scale, stride=1, padding=1,
                      stride=stride, padding=padding, bias=bias, scale=scale,
                      shift=shift, residual=residual, relu=relu,
                      rb_p=blk.rb_p, k_blk=blk.k_blk, c_blk=blk.c_blk,
-                     rb_q=blk.rb_q, order=blk.order,
+                     order=blk.order,
                      interpret=(impl == "interpret"))
 
 
@@ -173,7 +173,7 @@ def conv2d_bwd_weights(x, do, *, stride, padding, filter_rs, impl=None,
                          whole_plane=True, interpret=(impl == "interpret"))
     return conv2d_wu(x, do, stride=stride, padding=padding,
                      filter_rs=filter_rs, b_p=blk.rb_p, k_blk=blk.k_blk,
-                     c_blk=blk.c_blk, rb_q=blk.rb_q, whole_plane=False,
+                     c_blk=blk.c_blk, whole_plane=False,
                      interpret=(impl == "interpret"))
 
 

@@ -24,11 +24,6 @@ from repro.core.conv import (conv2d_chain_fwd, conv2d_train, conv2d_fwd,
 from repro.graph.etg import ETG, build_etg
 
 
-def _shard_map():
-    from repro.launch.mesh import shard_map_fn
-    return shard_map_fn()
-
-
 def apply_bn_updates(params, stats, bn_momentum):
     """Fold freshly collected batch statistics into the running BN stats —
     in place, on a params tree the caller owns (the post-SGD tree).  Shared
@@ -287,7 +282,10 @@ class GxM:
                 out = get(t.inputs[0]).mean(axis=(1, 2))
             elif t.op == "fc":
                 p = params[t.name]
-                out = get(t.inputs[0]) @ p["w"] + p["b"]
+                # f32 at full precision on every backend, like the conv
+                # kernels (a TPU's default f32 matmul takes bf16 passes)
+                out = jnp.dot(get(t.inputs[0]), p["w"],
+                              precision=jax.lax.Precision.HIGHEST) + p["b"]
             else:
                 raise ValueError(f"unknown op {t.op}")
             tensors[t.name] = out
@@ -316,8 +314,8 @@ class GxM:
         fwd = self.infer
         if mesh is not None:
             P = jax.sharding.PartitionSpec
-            fwd = _shard_map()(fwd, mesh=mesh, in_specs=(P(), P(axis)),
-                               out_specs=P(axis), check_rep=False)
+            fwd = jax.shard_map(fwd, mesh=mesh, in_specs=(P(), P(axis)),
+                                out_specs=P(axis), check_vma=False)
         return jax.jit(fwd, donate_argnums=(1,) if donate_input else ())
 
     # -- loss / steps ---------------------------------------------------------

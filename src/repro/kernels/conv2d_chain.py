@@ -43,7 +43,7 @@ def _band_conv(xb, L, blk, impl, residual):
     if impl == "xla" or not _lane_ok(c, k):
         return ref.conv2d_fused(xb, w, **kw)
     return conv2d_direct(xb, w, rb_p=blk.rb_p, k_blk=blk.k_blk,
-                         c_blk=blk.c_blk, rb_q=blk.rb_q, order=blk.order,
+                         c_blk=blk.c_blk, order=blk.order,
                          interpret=(impl == "interpret"), **kw)
 
 

@@ -5,15 +5,21 @@ TPU mapping of the paper's blocked direct convolution:
   * ``VLEN`` feature-map blocking  -> channels live in the lane dimension
     (NHWC / RSCK layouts, C and K innermost).
   * register blocking ``RB_P x RB_Q`` -> an MXU M-tile of ``RB_P`` output rows
-    by ``RB_Q`` output columns (M = RB_P*RB_Q), so each grid step is one
-    "microkernel invocation" computing an (RB_P*RB_Q, K_blk) output tile.
-    RB_Q defaults to the full row Q; blocking it is worthwhile for wide
-    images whose row band would not fit VMEM.
+    by the full output row (M = RB_P * Q', Q' = Q rounded up to the sublane
+    tile), so each grid step is one "microkernel invocation" computing an
+    (RB_P*Q', K_blk) output tile.
   * cache blocking (§II-B)          -> the input is *tiled*: each grid step
-    streams only the (RB_P-1)*stride + R row band (x (RB_Q-1)*stride + S
-    columns) x C_blk channels it actually reads, via unblocked BlockSpec
-    index_maps over a (N, K_b, P_b, Q_b, C_b) grid — the VMEM working set is
-    independent of H*W (see ``core.blocking.conv_working_set``).
+    streams only the halo'd row band it reads — ``RB_P + (R-1)//stride`` rows
+    x the full padded row x C_blk channels — so the VMEM working set is
+    independent of H (see ``core.blocking.conv_working_set``).
+  * stride                          -> the padded input is split once, in
+    XLA, into stride-phase planes (``phase_planes``): tap (r, s) of a
+    stride-``t`` window reads plane (r % t, s % t) at offset
+    (r // t, s // t) with unit stride, so every in-kernel read is a
+    contiguous static slice and a row band is a slab of the untiled leading
+    axis.  The band's element offsets therefore never touch the tiled
+    (cols, C) dims, whose blocks are whole or 128-lane aligned — the
+    windows Mosaic's DMA accepts.
   * C_b accumulation (§II-A alg. 4) -> input channels are blocked; an f32
     VMEM scratch accumulator is zero-initialized on the first C-block visit
     of an output tile and the fused epilogue fires on the last visit — the
@@ -26,16 +32,16 @@ TPU mapping of the paper's blocked direct convolution:
     ReLU epilogue fused into the same kernel, applied while the tile is in
     VMEM ("hot in cache").
   * loop order (§II-C)              -> the grid is laid out per ``order``
-    (a permutation of "nkpc", C innermost; Q rides with P), trading
-    weight-block vs input-band reuse exactly as in the paper.
+    (a permutation of "nkpc", C innermost), trading weight-block vs
+    input-band reuse exactly as in the paper.
   * two-level prefetch (§II-E)      -> the Mosaic grid pipeliner
     double-buffers the next step's blocks automatically.
 
-Inputs must be pre-padded (``pad_input``) so no in-kernel slice ever leaves
-the array — the bottom/right padding also covers the ceil-div grid tail,
-which is how the paper's "second kernel variant at the P/Q boundary" (§II-H)
-disappears on TPU: out-of-range output rows land in Pallas' masked
-out-of-bounds stores.
+The phase planes are zero-padded far enough that no band leaves the array —
+the bottom padding also covers the ceil-div row grid, which is how the
+paper's "second kernel variant at the P/Q boundary" (§II-H) disappears on
+TPU: out-of-range output rows land in Pallas' masked out-of-bounds stores,
+and the Q' - Q padding columns are sliced off after the call.
 
 The pre-refactor variant that shipped the whole padded input plane per image
 into VMEM on every grid step is kept as ``whole_plane=True`` (knob:
@@ -66,12 +72,10 @@ class FuseSpec:
         return int(self.bias) + 2 * int(self.bn) + int(self.residual)
 
 
-def pad_input(x, *, padding: int, stride: int, rb_p: int, r: int, p: int,
-              rb_q: int | None = None, s: int | None = None,
-              q: int | None = None):
-    """Spatially pad x (N,H,W,C) for the kernels: `padding` on all sides plus
-    bottom (and, with ``rb_q``, right) slack so the ceil-div grids never read
-    out of bounds.
+def pad_input(x, *, padding: int, stride: int, rb_p: int, r: int, p: int):
+    """Spatially pad x (N,H,W,C) for the whole-plane kernels: `padding` on
+    all sides plus bottom slack so the ceil-div row grid never reads out of
+    bounds.
 
     The bottom slack is exactly ``rows_needed - (h + padding)``: the grid's
     last row band ends at row ``(ceil(p/rb_p)*rb_p - 1)*stride + r`` of the
@@ -83,14 +87,85 @@ def pad_input(x, *, padding: int, stride: int, rb_p: int, r: int, p: int,
     p_b = math.ceil(p / rb_p)
     rows_needed = (p_b * rb_p - 1) * stride + r          # last row touched + 1
     pad_bottom = max(rows_needed - (h + padding), 0)
-    if rb_q is None:        # legacy full-row callers (wu / q8 kernels)
-        pad_right = padding
-    else:
-        q_b = math.ceil(q / rb_q)
-        cols_needed = (q_b * rb_q - 1) * stride + s      # last col touched + 1
-        pad_right = max(cols_needed - (w + padding), 0)
-    return jnp.pad(x, ((0, 0), (padding, pad_bottom), (padding, pad_right),
+    return jnp.pad(x, ((0, 0), (padding, pad_bottom), (padding, padding),
                        (0, 0)))
+
+
+def tile_cols(q: int, itemsize: int) -> int:
+    """Output columns per tile row: Q rounded up to the sublane tile of an
+    ``itemsize``-byte dtype (8 rows of 32 bits), so collapsing (rows, cols,
+    C) -> (rows*cols, C) for the MXU never splits a tile."""
+    pack = 8 * max(4 // itemsize, 1)
+    return -(-q // pack) * pack
+
+
+def phase_planes(x, *, padding: int, stride: int, r: int, s: int, p: int,
+                 rb_p: int, cols: int):
+    """Zero-pad x (N,H,W,C) and split it into stride-phase planes
+    (N, rows, min(stride, r), min(stride, s), cols + (s-1)//stride, C):
+    plane (a, b) holds padded pixel (i*stride + a, j*stride + b) at (i, j).
+
+    Output (pp, qq)'s tap (rr, ss) then reads plane (rr % stride,
+    ss % stride) at (pp + rr//stride, qq + ss//stride) — unit stride for
+    every stride.  ``rows`` covers the ceil-div row grid plus the halo;
+    ``cols`` is the padded output row width (``tile_cols``).  For stride 1
+    this is a pad plus a free reshape."""
+    n, h, w, c = x.shape
+    rows = math.ceil(p / rb_p) * rb_p + (r - 1) // stride
+    cols = cols + (s - 1) // stride
+    hp, wp = rows * stride, cols * stride
+    xp = jnp.pad(x, ((0, 0), (padding, max(hp - h - padding, 0)),
+                     (padding, max(wp - w - padding, 0)), (0, 0)))
+    xp = xp[:, :hp, :wp].reshape(n, rows, stride, cols, stride, c)
+    xp = xp[:, :, :min(stride, r), :, :min(stride, s)]
+    return xp.transpose(0, 1, 2, 4, 3, 5)
+
+
+def band_spec(planes_shape, *, rb_p: int, r: int, stride: int, c_blk: int,
+              n_axis: int, p_axis: int, c_axis: int):
+    """BlockSpec streaming one halo'd row band per grid step: rows
+    [pi*rb_p, pi*rb_p + rb_p + (r-1)//stride) of every phase plane, the full
+    row, one C block.  Consecutive bands overlap by the halo, so the row
+    offset is an element index — on the untiled leading axis; the tiled
+    (cols, C) dims are read whole or at 128-lane aligned C offsets.
+    Returns the spec and the band's shape."""
+    _, _, ah, aw, cols, c = planes_shape
+    shape = (1, rb_p + (r - 1) // stride, ah, aw, cols, c_blk)
+    c_b = c // c_blk
+
+    def index(*i):
+        c_off = i[c_axis] * c_blk if c_b > 1 else 0
+        return (i[n_axis], i[p_axis] * rb_p, 0, 0, 0, c_off)
+
+    return pl.BlockSpec(tuple(pl.Element(d) for d in shape), index), shape
+
+
+def band_tap(x_ref, rr: int, ss: int, *, rows: int, cols: int, stride: int):
+    """Tap (rr, ss)'s (rows*cols, C_blk) GEMM operand, read from the band."""
+    xs = x_ref[0, pl.dslice(rr // stride, rows), rr % stride, ss % stride,
+               pl.dslice(ss // stride, cols), :]
+    return xs.reshape(rows * cols, xs.shape[-1])
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """One VMEM buffer of ``shape``, its two minor dims padded to the
+    (8 x 32-bit sublane, 128-lane) tile."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, sub, lane = (1, *shape) if len(shape) == 1 else shape
+    sub_tile = 8 * max(4 // itemsize, 1)
+    return (math.prod(lead) * (-(-sub // sub_tile) * sub_tile)
+            * (-(-lane // 128) * 128) * itemsize)
+
+
+def compiler_params(semantics, *, blocks, scratch=()):
+    """Mosaic parameters with an explicit scoped-VMEM limit: every pipelined
+    block double-buffered plus the scratch, tile-padded, plus headroom for
+    the unrolled tap chain's temporaries.  ``blocks``/``scratch`` are
+    (shape, dtype) pairs."""
+    need = (2 * sum(_vmem_bytes(sh, dt) for sh, dt in blocks)
+            + sum(_vmem_bytes(sh, dt) for sh, dt in scratch))
+    return pltpu.CompilerParams(dimension_semantics=tuple(semantics),
+                                vmem_limit_bytes=need + (16 << 20))
 
 
 def _unpack_fuse_refs(refs, fuse: FuseSpec):
@@ -121,7 +196,7 @@ def _epilogue(acc, fuse: FuseSpec, bias_ref, scale_ref, shift_ref, res_ref,
 
 
 def _kernel_tiled(x_ref, w_ref, *refs, fuse: FuseSpec, rb_p: int,
-                  rb_q: int, stride: int, r: int, s: int, c_axis: int,
+                  cols: int, stride: int, r: int, s: int, c_axis: int,
                   accum_dtype, out_dtype):
     """One microkernel invocation on a streamed row band: accumulate one
     C-block into the scratch tile; init on the first visit, epilogue + store
@@ -137,26 +212,24 @@ def _kernel_tiled(x_ref, w_ref, *refs, fuse: FuseSpec, rb_p: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    c_blk = x_ref.shape[-1]
     k_blk = w_ref.shape[-1]
-    acc = jnp.zeros((rb_p * rb_q, k_blk), dtype=accum_dtype)
+    acc = jnp.zeros((rb_p * cols, k_blk), dtype=accum_dtype)
     # The paper's perfectly-chained small-GEMM sequence over (r, s); the
     # band's row 0 is this step's first window row, so offsets are local.
     for rr in range(r):
         for ss in range(s):
-            xs = x_ref[0, pl.dslice(rr, rb_p, stride),
-                       pl.dslice(ss, rb_q, stride), :]   # (rb_p, rb_q, c_blk)
-            a = xs.reshape(rb_p * rb_q, c_blk)
+            a = band_tap(x_ref, rr, ss, rows=rb_p, cols=cols, stride=stride)
             acc += jax.lax.dot(a.astype(accum_dtype), w_ref[rr, ss, :, :]
                                .astype(accum_dtype),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=accum_dtype)
     acc_ref[...] += acc
 
     @pl.when(ci == c_b - 1)
     def _finish():
         out = _epilogue(acc_ref[...], fuse, bias_ref, scale_ref, shift_ref,
-                        res_ref, rb_p * rb_q, k_blk, accum_dtype)
-        o_ref[0] = out.reshape(rb_p, rb_q, k_blk).astype(out_dtype)
+                        res_ref, rb_p * cols, k_blk, accum_dtype)
+        o_ref[0] = out.reshape(rb_p, cols, k_blk).astype(out_dtype)
 
 
 def _kernel_whole(x_ref, w_ref, *refs, fuse: FuseSpec, rb_p: int, q: int,
@@ -185,48 +258,43 @@ def _kernel_whole(x_ref, w_ref, *refs, fuse: FuseSpec, rb_p: int, q: int,
     o_ref[0] = acc.reshape(rb_p, q, k_blk).astype(out_dtype)
 
 
-def _grid_layout(order: str, *, n: int, k_b: int, p_b: int, q_b: int,
-                 c_b: int):
+def _grid_layout(order: str, *, n: int, k_b: int, p_b: int, c_b: int):
     """Grid extents laid out per the §II-C loop order.  ``order`` permutes
     (n, k, p, c) with C innermost (the accumulator tile lives across the
-    C sweep); the Q_b axis always rides directly inside P_b."""
+    C sweep).  Returns (grid, axis index per letter, Mosaic dimension
+    semantics — every axis but the C accumulation is parallel)."""
     assert sorted(order) == sorted("nkpc"), order
     assert order.endswith("c"), "C-blocks must be innermost (accumulator)"
-    axis: dict[str, int] = {}
-    dims: list[int] = []
-    for ch in order:
-        if ch == "p":
-            axis["p"] = len(dims); dims.append(p_b)
-            axis["q"] = len(dims); dims.append(q_b)
-        else:
-            axis[ch] = len(dims)
-            dims.append({"n": n, "k": k_b, "c": c_b}[ch])
-    return tuple(dims), axis
+    ext = {"n": n, "k": k_b, "p": p_b, "c": c_b}
+    axis = {ch: i for i, ch in enumerate(order)}
+    semantics = ["arbitrary" if ch == "c" else "parallel" for ch in order]
+    return tuple(ext[ch] for ch in order), axis, semantics
 
 
 def conv2d_direct(x, w, *, stride: int = 1, padding: int = 0,
                   bias=None, scale=None, shift=None, residual=None,
                   relu: bool = False, rb_p: int = 8, k_blk: int | None = None,
-                  c_blk: int | None = None, rb_q: int | None = None,
-                  order: str = "nkpc", whole_plane: bool | None = None,
+                  c_blk: int | None = None, order: str = "nkpc",
+                  whole_plane: bool | None = None,
                   accum_dtype=jnp.float32, interpret: bool = False):
     """Direct conv fwd.  x: (N,H,W,C), w: (R,S,C,K) -> (N,P,Q,K).
 
-    `rb_p`/`rb_q` are the paper's RB_P/RB_Q register blocks (output rows /
-    columns per microkernel; `rb_q=None` = the full row).  `k_blk` is the
-    output-feature block (paper: the vectorized K_b loop); defaults to
-    min(K, 128) = one MXU N-tile.  `c_blk` blocks the input features
-    (paper C_b; `None` = unblocked): the output tile is then revisited
-    across C-block grid steps and accumulated in an f32 VMEM scratch.
-    `order` is the §II-C loop order of the grid.  `whole_plane` selects the
-    legacy untiled kernel (default: the ``repro.backend`` conv-tiling knob).
+    `rb_p` is the paper's RB_P register block (output rows per microkernel;
+    each covers the full output row).  `k_blk` is the output-feature block
+    (paper: the vectorized K_b loop); defaults to min(K, 128) = one MXU
+    N-tile.  `c_blk` blocks the input features (paper C_b; `None` =
+    unblocked): the output tile is then revisited across C-block grid steps
+    and accumulated in an f32 VMEM scratch.  On the chip both feature blocks
+    must be the whole dim or a multiple of 128 (``core.blocking`` only
+    emits such blocks).  `order` is the §II-C loop order of the grid.
+    `whole_plane` selects the legacy untiled kernel (default: the
+    ``repro.backend`` conv-tiling knob).
     """
     n, h, wdt, c = x.shape
     r, s, _, k = w.shape
     p = (h + 2 * padding - r) // stride + 1
     q = (wdt + 2 * padding - s) // stride + 1
     rb_p = min(rb_p, p)
-    rb_q = q if rb_q in (None, 0) else min(rb_q, q)
     if k_blk is None:
         k_blk = min(k, 128)
     c_blk = c if c_blk in (None, 0) else c_blk
@@ -242,13 +310,12 @@ def conv2d_direct(x, w, *, stride: int = 1, padding: int = 0,
         assert shift is not None
 
     p_b = math.ceil(p / rb_p)
-    q_b = math.ceil(q / rb_q)
     k_b = k // k_blk
     c_b = c // c_blk
     out_dtype = x.dtype
 
     if whole_plane:
-        # the legacy kernel has no C/Q blocking or order freedom — when the
+        # the legacy kernel has no C blocking or order freedom — when the
         # "whole" knob overrides a tiled blocking, those axes collapse
         return _conv2d_whole_plane(
             x, w, fuse=fuse, stride=stride, padding=padding, bias=bias,
@@ -257,51 +324,49 @@ def conv2d_direct(x, w, *, stride: int = 1, padding: int = 0,
             accum_dtype=accum_dtype, out_dtype=out_dtype,
             interpret=interpret)
 
-    xp = pad_input(x, padding=padding, stride=stride, rb_p=rb_p, r=r, p=p,
-                   rb_q=rb_q, s=s, q=q)
-    band_h = (rb_p - 1) * stride + r
-    band_w = (rb_q - 1) * stride + s
-    grid, axis = _grid_layout(order, n=n, k_b=k_b, p_b=p_b, q_b=q_b, c_b=c_b)
-    an, ak, ap, aq, ac = (axis[d] for d in "nkpqc")
+    cols = tile_cols(q, x.dtype.itemsize)
+    xp = phase_planes(x, padding=padding, stride=stride, r=r, s=s, p=p,
+                      rb_p=rb_p, cols=cols)
+    grid, axis, semantics = _grid_layout(order, n=n, k_b=k_b, p_b=p_b,
+                                         c_b=c_b)
+    an, ak, ap, ac = (axis[d] for d in "nkpc")
 
-    # Row-band streaming: unblocked indexing (element offsets), because
-    # consecutive bands overlap by the (r - stride)-row halo and so are not
-    # aligned to any fixed block size.  pad_input guarantees the last band
-    # stays in bounds.
-    in_specs = [
-        pl.BlockSpec((1, band_h, band_w, c_blk),
-                     lambda *i: (i[an], i[ap] * rb_p * stride,
-                                 i[aq] * rb_q * stride, i[ac] * c_blk),
-                     indexing_mode=pl.unblocked),
-        pl.BlockSpec((r, s, c_blk, k_blk),
-                     lambda *i: (0, 0, i[ac], i[ak])),
-    ]
+    x_spec, band = band_spec(xp.shape, rb_p=rb_p, r=r, stride=stride,
+                             c_blk=c_blk, n_axis=an, p_axis=ap, c_axis=ac)
+    tile = (1, rb_p, cols, k_blk)
+    in_specs = [x_spec, pl.BlockSpec((r, s, c_blk, k_blk),
+                                     lambda *i: (0, 0, i[ac], i[ak]))]
     args = [xp, w]
-    if fuse.bias:
-        in_specs.append(pl.BlockSpec((1, k_blk), lambda *i: (0, i[ak])))
-        args.append(bias.reshape(1, k))
-    if fuse.bn:
-        in_specs.append(pl.BlockSpec((1, k_blk), lambda *i: (0, i[ak])))
-        in_specs.append(pl.BlockSpec((1, k_blk), lambda *i: (0, i[ak])))
-        args.extend([scale.reshape(1, k), shift.reshape(1, k)])
+    blocks = [(band, x.dtype), ((r, s, c_blk, k_blk), w.dtype),
+              (tile, out_dtype)]
+    for vec in (bias, scale, shift):
+        if vec is not None:
+            in_specs.append(pl.BlockSpec((1, k_blk), lambda *i: (0, i[ak])))
+            args.append(vec.reshape(1, k))
+            blocks.append(((1, k_blk), vec.dtype))
     if fuse.residual:
-        in_specs.append(pl.BlockSpec((1, rb_p, rb_q, k_blk),
-                                     lambda *i: (i[an], i[ap], i[aq], i[ak])))
-        args.append(residual)
+        in_specs.append(pl.BlockSpec(tile,
+                                     lambda *i: (i[an], i[ap], 0, i[ak])))
+        args.append(jnp.pad(residual, ((0, 0), (0, 0), (0, cols - q),
+                                       (0, 0))))
+        blocks.append((tile, residual.dtype))
 
-    kern = functools.partial(_kernel_tiled, fuse=fuse, rb_p=rb_p, rb_q=rb_q,
+    kern = functools.partial(_kernel_tiled, fuse=fuse, rb_p=rb_p, cols=cols,
                              stride=stride, r=r, s=s, c_axis=ac,
                              accum_dtype=accum_dtype, out_dtype=out_dtype)
-    return pl.pallas_call(
+    acc = (rb_p * cols, k_blk)
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rb_p, rb_q, k_blk),
-                               lambda *i: (i[an], i[ap], i[aq], i[ak])),
-        out_shape=jax.ShapeDtypeStruct((n, p, q, k), out_dtype),
-        scratch_shapes=[pltpu.VMEM((rb_p * rb_q, k_blk), accum_dtype)],
+        out_specs=pl.BlockSpec(tile, lambda *i: (i[an], i[ap], 0, i[ak])),
+        out_shape=jax.ShapeDtypeStruct((n, p, cols, k), out_dtype),
+        scratch_shapes=[pltpu.VMEM(acc, accum_dtype)],
+        compiler_params=compiler_params(semantics, blocks=blocks,
+                                        scratch=[(acc, accum_dtype)]),
         interpret=interpret,
     )(*args)
+    return out[:, :, :q] if cols != q else out
 
 
 def _conv2d_whole_plane(x, w, *, fuse, stride, padding, bias, scale, shift,

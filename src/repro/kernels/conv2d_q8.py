@@ -13,9 +13,9 @@ R=S=3, C=2048 is ~3e8 << 2^31 — checked statically below (the paper had to
 gives us the headroom for free, which is exactly why serving stacks picked
 int8).
 
-The kernel is tiled exactly like ``conv2d_direct``: a (N, K_b, P_b, Q_b,
-C_b) grid streaming only the (RB_P-1)*stride + R row band per step via
-unblocked BlockSpec index_maps, with an *int32* VMEM scratch accumulated
+The kernel is tiled exactly like ``conv2d_direct``: a (N, K_b, P_b, C_b)
+grid streaming only the halo'd row band of the stride-phase planes per step
+(``conv2d_direct.band_spec``), with an *int32* VMEM scratch accumulated
 across C-block visits (init on the first visit, dequant + fused §II-G
 epilogue + store on the last).  int8 bands are 4x smaller than f32 ones, so
 ``core.blocking.conv_working_set(kind="q8")`` lets RB_P grow ~4x under the
@@ -36,7 +36,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.conv2d_direct import (FuseSpec, _epilogue, _grid_layout,
-                                         _unpack_fuse_refs, pad_input)
+                                         _unpack_fuse_refs, band_spec,
+                                         band_tap, compiler_params, pad_input,
+                                         phase_planes, tile_cols)
 
 
 def _check_overflow(r: int, s: int, c: int) -> None:
@@ -45,7 +47,7 @@ def _check_overflow(r: int, s: int, c: int) -> None:
 
 
 def _kernel_q8_tiled(x_ref, w_ref, deq_ref, *refs, fuse: FuseSpec, rb_p: int,
-                     rb_q: int, stride: int, r: int, s: int, c_axis: int,
+                     cols: int, stride: int, r: int, s: int, c_axis: int,
                      out_dtype):
     """One microkernel invocation on a streamed int8 row band: accumulate one
     C-block into the int32 scratch; init on the first visit, dequantize +
@@ -61,17 +63,13 @@ def _kernel_q8_tiled(x_ref, w_ref, deq_ref, *refs, fuse: FuseSpec, rb_p: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    c_blk = x_ref.shape[-1]
     k_blk = w_ref.shape[-1]
-    acc = jnp.zeros((rb_p * rb_q, k_blk), dtype=jnp.int32)
+    acc = jnp.zeros((rb_p * cols, k_blk), dtype=jnp.int32)
     for rr in range(r):
         for ss in range(s):
-            xs = x_ref[0, pl.dslice(rr, rb_p, stride),
-                       pl.dslice(ss, rb_q, stride), :]   # (rb_p, rb_q, c_blk)
-            a = xs.reshape(rb_p * rb_q, c_blk)
+            a = band_tap(x_ref, rr, ss, rows=rb_p, cols=cols, stride=stride)
             # int8 x int8 -> int32 accumulate (the 4VNNIW analog)
-            acc += jax.lax.dot(a.astype(jnp.int32),
-                               w_ref[rr, ss, :, :].astype(jnp.int32),
+            acc += jax.lax.dot(a, w_ref[rr, ss, :, :],
                                preferred_element_type=jnp.int32)
     acc_ref[...] += acc
 
@@ -81,8 +79,8 @@ def _kernel_q8_tiled(x_ref, w_ref, deq_ref, *refs, fuse: FuseSpec, rb_p: int,
         # §II-G chain — bit-identical to the whole-plane kernel's epilogue
         out = acc_ref[...].astype(jnp.float32) * deq_ref[0, :]
         out = _epilogue(out, fuse, bias_ref, scale_ref, shift_ref, res_ref,
-                        rb_p * rb_q, k_blk, jnp.float32)
-        o_ref[0] = out.reshape(rb_p, rb_q, k_blk).astype(out_dtype)
+                        rb_p * cols, k_blk, jnp.float32)
+        o_ref[0] = out.reshape(rb_p, cols, k_blk).astype(out_dtype)
 
 
 def _kernel_q8_whole(x_ref, w_ref, deq_ref, *refs, fuse: FuseSpec, rb_p: int,
@@ -116,7 +114,7 @@ def conv2d_q8(x_q, w_q, *, x_scale, w_scale, stride: int = 1,
               padding: int = 0, bias=None, scale=None, shift=None,
               residual=None, relu: bool = False, rb_p: int = 8,
               k_blk: int | None = None, c_blk: int | None = None,
-              rb_q: int | None = None, order: str = "nkpc",
+              order: str = "nkpc",
               whole_plane: bool | None = None, out_dtype=jnp.float32,
               interpret: bool = False):
     """Quantized direct conv fwd.  x_q: (N,H,W,C) int8; w_q: (R,S,C,K) int8;
@@ -124,7 +122,7 @@ def conv2d_q8(x_q, w_q, *, x_scale, w_scale, stride: int = 1,
     per-output-channel.  -> (N,P,Q,K) out_dtype (f32 by default — output
     bandwidth stays 32-bit, the paper's reason 1.6x != 4x).
 
-    Blocking kwargs mirror ``conv2d_direct`` (`rb_p`/`rb_q` register block,
+    Blocking kwargs mirror ``conv2d_direct`` (`rb_p` full-row register block,
     `k_blk` MXU N-tile, `c_blk` C-block accumulated in int32 VMEM scratch,
     `order` the §II-C grid order); `whole_plane` selects the legacy untiled
     kernel (default: the ``repro.backend`` conv-tiling knob).  The optional
@@ -138,7 +136,6 @@ def conv2d_q8(x_q, w_q, *, x_scale, w_scale, stride: int = 1,
     p = (h + 2 * padding - r) // stride + 1
     q = (wdt + 2 * padding - s) // stride + 1
     rb_p = min(rb_p, p)
-    rb_q = q if rb_q in (None, 0) else min(rb_q, q)
     k_blk = k_blk or min(k, 128)
     c_blk = c if c_blk in (None, 0) else c_blk
     assert k % k_blk == 0, (k, k_blk)
@@ -165,52 +162,55 @@ def conv2d_q8(x_q, w_q, *, x_scale, w_scale, stride: int = 1,
             out_dtype=out_dtype, interpret=interpret)
 
     p_b = math.ceil(p / rb_p)
-    q_b = math.ceil(q / rb_q)
     k_b = k // k_blk
     c_b = c // c_blk
 
-    xp = pad_input(x_q, padding=padding, stride=stride, rb_p=rb_p, r=r, p=p,
-                   rb_q=rb_q, s=s, q=q)
-    band_h = (rb_p - 1) * stride + r
-    band_w = (rb_q - 1) * stride + s
-    grid, axis = _grid_layout(order, n=n, k_b=k_b, p_b=p_b, q_b=q_b, c_b=c_b)
-    an, ak, ap, aq, ac = (axis[d] for d in "nkpqc")
+    cols = tile_cols(q, x_q.dtype.itemsize)
+    xp = phase_planes(x_q, padding=padding, stride=stride, r=r, s=s, p=p,
+                      rb_p=rb_p, cols=cols)
+    grid, axis, semantics = _grid_layout(order, n=n, k_b=k_b, p_b=p_b,
+                                         c_b=c_b)
+    an, ak, ap, ac = (axis[d] for d in "nkpc")
 
+    x_spec, band = band_spec(xp.shape, rb_p=rb_p, r=r, stride=stride,
+                             c_blk=c_blk, n_axis=an, p_axis=ap, c_axis=ac)
+    tile = (1, rb_p, cols, k_blk)
     in_specs = [
-        pl.BlockSpec((1, band_h, band_w, c_blk),
-                     lambda *i: (i[an], i[ap] * rb_p * stride,
-                                 i[aq] * rb_q * stride, i[ac] * c_blk),
-                     indexing_mode=pl.unblocked),
-        pl.BlockSpec((r, s, c_blk, k_blk),
-                     lambda *i: (0, 0, i[ac], i[ak])),
+        x_spec,
+        pl.BlockSpec((r, s, c_blk, k_blk), lambda *i: (0, 0, i[ac], i[ak])),
         pl.BlockSpec((1, k_blk), lambda *i: (0, i[ak])),     # deq scales
     ]
     args = [xp, w_q, deq]
-    if fuse.bias:
-        in_specs.append(pl.BlockSpec((1, k_blk), lambda *i: (0, i[ak])))
-        args.append(bias.reshape(1, k))
-    if fuse.bn:
-        in_specs.append(pl.BlockSpec((1, k_blk), lambda *i: (0, i[ak])))
-        in_specs.append(pl.BlockSpec((1, k_blk), lambda *i: (0, i[ak])))
-        args.extend([scale.reshape(1, k), shift.reshape(1, k)])
+    blocks = [(band, jnp.int8), ((r, s, c_blk, k_blk), jnp.int8),
+              ((1, k_blk), jnp.float32), (tile, out_dtype)]
+    for vec in (bias, scale, shift):
+        if vec is not None:
+            in_specs.append(pl.BlockSpec((1, k_blk), lambda *i: (0, i[ak])))
+            args.append(vec.reshape(1, k))
+            blocks.append(((1, k_blk), vec.dtype))
     if fuse.residual:
-        in_specs.append(pl.BlockSpec((1, rb_p, rb_q, k_blk),
-                                     lambda *i: (i[an], i[ap], i[aq], i[ak])))
-        args.append(residual)
+        in_specs.append(pl.BlockSpec(tile,
+                                     lambda *i: (i[an], i[ap], 0, i[ak])))
+        args.append(jnp.pad(residual, ((0, 0), (0, 0), (0, cols - q),
+                                       (0, 0))))
+        blocks.append((tile, residual.dtype))
 
     kern = functools.partial(_kernel_q8_tiled, fuse=fuse, rb_p=rb_p,
-                             rb_q=rb_q, stride=stride, r=r, s=s, c_axis=ac,
+                             cols=cols, stride=stride, r=r, s=s, c_axis=ac,
                              out_dtype=out_dtype)
-    return pl.pallas_call(
+    acc = (rb_p * cols, k_blk)
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rb_p, rb_q, k_blk),
-                               lambda *i: (i[an], i[ap], i[aq], i[ak])),
-        out_shape=jax.ShapeDtypeStruct((n, p, q, k), out_dtype),
-        scratch_shapes=[pltpu.VMEM((rb_p * rb_q, k_blk), jnp.int32)],
+        out_specs=pl.BlockSpec(tile, lambda *i: (i[an], i[ap], 0, i[ak])),
+        out_shape=jax.ShapeDtypeStruct((n, p, cols, k), out_dtype),
+        scratch_shapes=[pltpu.VMEM(acc, jnp.int32)],
+        compiler_params=compiler_params(semantics, blocks=blocks,
+                                        scratch=[(acc, jnp.int32)]),
         interpret=interpret,
     )(*args)
+    return out[:, :, :q] if cols != q else out
 
 
 def _conv2d_q8_whole_plane(x_q, w_q, deq, *, fuse, stride, padding, bias,

@@ -8,20 +8,20 @@ K=B_P*B_Q — the transpose-free analog of the paper's VLENxVLEN microkernel
 blocking up to VLEN" becomes a (C_blk, K_blk) accumulator tile resident in
 VMEM).
 
-Tiled (default, the PR-3 forward discipline brought to the update pass):
+Tiled (default, the forward kernel's discipline brought to the update pass):
 
-  * the grid is ``(K_b, C_b, N, P_b, Q_b)`` — the dW tile index depends only
-    on the two outer axes, so the Pallas revisiting-output pattern keeps one
-    (r, s, C_blk, K_blk) f32 tile in VMEM across the whole (n, p, q) sweep,
+  * the grid is ``(K_b, C_b, N, P_b)`` — the dW tile index depends only on
+    the two outer axes, so the Pallas revisiting-output pattern keeps one
+    (r, s, C_blk, K_blk) f32 tile in VMEM across the whole (n, p) sweep,
     zero-initialized on the first visit of each (k, c) block pair;
-  * the input BlockSpec streams only the ``(b_p-1)*stride + r`` row band
-    (x ``(rb_q-1)*stride + s`` columns x C_blk channels) each step actually
-    reads, via unblocked index_maps over the padded plane — the VMEM working
-    set is independent of H*W (``core.blocking.conv_working_set``);
-  * P and Q use ceil-div grids: the dO tail block's out-of-range rows/cols
-    are masked to zero in-kernel (loads of a tail input block are allowed but
-    carry garbage), so every layer schedules — no ``P % b_p == 0``
-    restriction, the 224x224 7x7 stem included.
+  * the input streams only the halo'd row band each step reads, as stride-
+    phase planes with element offsets on the untiled leading axis
+    (``conv2d_direct.phase_planes`` / ``band_spec``) — the VMEM working set
+    is independent of H (``core.blocking.conv_working_set``);
+  * P uses a ceil-div grid: dO is zero-padded to whole row blocks and to the
+    sublane-rounded row width, so padding pixels contribute nothing and
+    every layer schedules — no ``P % b_p == 0`` restriction, the 224x224
+    7x7 stem included.
 
 The pre-refactor variant that shipped the **entire padded input plane per
 image** into VMEM at every grid step (and could not block C or Q, and
@@ -42,42 +42,30 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.conv2d_direct import pad_input
+from repro.kernels.conv2d_direct import (band_spec, band_tap,
+                                         compiler_params, pad_input,
+                                         phase_planes, tile_cols)
 
 
-def _kernel_tiled(x_ref, do_ref, o_ref, *, b_p: int, rb_q: int, stride: int,
-                  r: int, s: int, p: int, q: int, accum_dtype):
-    """One band-streamed update-pass step: accumulate this (n, p, q) block's
+def _kernel_tiled(x_ref, do_ref, o_ref, *, b_p: int, cols: int, stride: int,
+                  r: int, s: int, accum_dtype):
+    """One band-streamed update-pass step: accumulate this (n, p) block's
     contribution into the resident (r, s, C_blk, K_blk) dW tile."""
-    ni = pl.program_id(2)
-    pb = pl.program_id(3)
-    qb = pl.program_id(4)
-
-    first = jnp.logical_and(jnp.logical_and(ni == 0, pb == 0), qb == 0)
+    first = jnp.logical_and(pl.program_id(2) == 0, pl.program_id(3) == 0)
 
     @pl.when(first)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    c_blk = x_ref.shape[-1]
     k_blk = do_ref.shape[-1]
-    g = do_ref[0].astype(accum_dtype)                 # (b_p, rb_q, k_blk)
-    if p % b_p or q % rb_q:
-        # ceil-div tail: the dO block read past (P, Q) is garbage — zero it
-        # so it contributes nothing to the accumulation (the fwd kernel's
-        # masked-store trick is not available here: dO is an *input*).
-        rows = pb * b_p + jax.lax.broadcasted_iota(jnp.int32, (b_p, rb_q), 0)
-        cols = qb * rb_q + jax.lax.broadcasted_iota(jnp.int32, (b_p, rb_q), 1)
-        g = jnp.where(((rows < p) & (cols < q))[..., None], g, 0)
-    g = g.reshape(b_p * rb_q, k_blk)
+    g = do_ref[0].reshape(b_p * cols, k_blk).astype(accum_dtype)
     for rr in range(r):
         for ss in range(s):
-            xs = x_ref[0, pl.dslice(rr, b_p, stride),
-                       pl.dslice(ss, rb_q, stride), :]    # (b_p, rb_q, c_blk)
-            a = xs.reshape(b_p * rb_q, c_blk).astype(accum_dtype)
+            a = band_tap(x_ref, rr, ss, rows=b_p, cols=cols, stride=stride)
             # dW[r,s] += A^T @ G : contract over the pixel block.
             o_ref[rr, ss, :, :] += jax.lax.dot_general(
-                a, g, (((0,), (0,)), ((), ())),
+                a.astype(accum_dtype), g, (((0,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=accum_dtype)
 
 
@@ -110,17 +98,17 @@ def _kernel_whole(x_ref, do_ref, o_ref, *, b_p: int, q: int, stride: int,
 def conv2d_wu(x, do, *, stride: int = 1, padding: int = 0,
               filter_rs: tuple[int, int], b_p: int = 7,
               k_blk: int | None = None, c_blk: int | None = None,
-              rb_q: int | None = None, accum_dtype=jnp.float32,
-              whole_plane: bool | None = None, interpret: bool = False):
+              accum_dtype=jnp.float32, whole_plane: bool | None = None,
+              interpret: bool = False):
     """dW (R,S,C,K) from x (N,H,W,C) and dO (N,P,Q,K).
 
-    ``b_p``/``rb_q`` are the paper's B_P/B_Q spatial blocking of the update
-    pass (``rb_q=None`` = the full row); ``k_blk``/``c_blk`` block the
-    output/input features (``c_blk=None`` = unblocked).  P and Q grids are
-    ceil-div — tails are masked in-kernel, so no divisibility of the spatial
-    dims is required.  ``whole_plane`` selects the legacy resident-plane
-    kernel (default: the ``repro.backend`` conv-tiling knob); that path keeps
-    the seed's ``P % b_p == 0`` restriction.
+    ``b_p`` is the paper's B_P spatial blocking of the update pass (output
+    rows per step, each the full row); ``k_blk``/``c_blk`` block the
+    output/input features (``c_blk=None`` = unblocked).  The P grid is
+    ceil-div over a zero-padded dO, so no divisibility of the spatial dims
+    is required.  ``whole_plane`` selects the legacy resident-plane kernel
+    (default: the ``repro.backend`` conv-tiling knob); that path keeps the
+    seed's ``P % b_p == 0`` restriction.
     """
     n, h, wdt, c = x.shape
     _, p, q, k = do.shape
@@ -138,45 +126,39 @@ def conv2d_wu(x, do, *, stride: int = 1, padding: int = 0,
                                 r=r, s=s, b_p=b_p, k_blk=k_blk,
                                 accum_dtype=accum_dtype, interpret=interpret)
 
-    rb_q = q if rb_q in (None, 0) else min(rb_q, q)
     c_blk = c if c_blk in (None, 0) else c_blk
     assert c % c_blk == 0, (c, c_blk)
 
-    xp = pad_input(x, padding=padding, stride=stride, rb_p=b_p, r=r, p=p,
-                   rb_q=rb_q, s=s, q=q)
-    band_h = (b_p - 1) * stride + r
-    band_w = (rb_q - 1) * stride + s
+    cols = tile_cols(q, x.dtype.itemsize)
     p_b = math.ceil(p / b_p)
-    q_b = math.ceil(q / rb_q)
-    k_b = k // k_blk
-    c_b = c // c_blk
-    # dW tile constant over the inner (n, p_b, q_b) sweep -> one VMEM-resident
+    xp = phase_planes(x, padding=padding, stride=stride, r=r, s=s, p=p,
+                      rb_p=b_p, cols=cols)
+    # zero rows/cols past (P, Q): the padded pixels contribute nothing
+    dop = jnp.pad(do, ((0, 0), (0, p_b * b_p - p), (0, cols - q), (0, 0)))
+    # dW tile constant over the inner (n, p_b) sweep -> one VMEM-resident
     # accumulation pass per (k, c) block pair.
-    grid = (k_b, c_b, n, p_b, q_b)
+    grid = (k // k_blk, c // c_blk, n, p_b)
+    x_spec, band = band_spec(xp.shape, rb_p=b_p, r=r, stride=stride,
+                             c_blk=c_blk, n_axis=2, p_axis=3, c_axis=1)
+    do_tile = (1, b_p, cols, k_blk)
+    dw_tile = (r, s, c_blk, k_blk)
 
-    kern = functools.partial(_kernel_tiled, b_p=b_p, rb_q=rb_q, stride=stride,
-                             r=r, s=s, p=p, q=q, accum_dtype=accum_dtype)
+    kern = functools.partial(_kernel_tiled, b_p=b_p, cols=cols, stride=stride,
+                             r=r, s=s, accum_dtype=accum_dtype)
     out = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[
-            # Row-band streaming: unblocked indexing (element offsets) —
-            # consecutive bands overlap by the (r - stride)-row halo and are
-            # not aligned to any fixed block size.  pad_input guarantees the
-            # last band stays in bounds.
-            pl.BlockSpec((1, band_h, band_w, c_blk),
-                         lambda ki, ci, ni, pi, qi:
-                             (ni, pi * b_p * stride, qi * rb_q * stride,
-                              ci * c_blk),
-                         indexing_mode=pl.unblocked),
-            pl.BlockSpec((1, b_p, rb_q, k_blk),
-                         lambda ki, ci, ni, pi, qi: (ni, pi, qi, ki)),
-        ],
-        out_specs=pl.BlockSpec((r, s, c_blk, k_blk),
-                               lambda ki, ci, ni, pi, qi: (0, 0, ci, ki)),
+        in_specs=[x_spec,
+                  pl.BlockSpec(do_tile,
+                               lambda ki, ci, ni, pi: (ni, pi, 0, ki))],
+        out_specs=pl.BlockSpec(dw_tile, lambda ki, ci, ni, pi: (0, 0, ci, ki)),
         out_shape=jax.ShapeDtypeStruct((r, s, c, k), accum_dtype),
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary", "arbitrary"),
+            blocks=[(band, x.dtype), (do_tile, do.dtype),
+                    (dw_tile, accum_dtype)]),
         interpret=interpret,
-    )(xp, do)
+    )(xp, dop)
     return out.astype(x.dtype)
 
 

@@ -5,6 +5,10 @@ These are the ground truth used by tests (``assert_allclose`` against
 implementation that the 512-device lowering uses — Mosaic kernels only lower
 on real TPUs).
 
+The convolutions run at ``Precision.HIGHEST``, like the Pallas kernels: a
+TPU's default f32 convolution takes bf16 passes, which would make neither an
+oracle nor the f32 path of the model.
+
 Conventions (TPU adaptation of the paper's blocked layouts, see DESIGN.md §2):
   activations  : NHWC   (C innermost = lane dimension)
   weights      : RSCK   (K innermost = lane dimension)
@@ -29,6 +33,7 @@ def conv2d(x, w, *, stride: int = 1, padding: int = 0,
         window_strides=(stride, stride),
         padding=[(padding, padding), (padding, padding)],
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=lax.Precision.HIGHEST,
     )
     return out.astype(x.dtype)
 
@@ -48,6 +53,7 @@ def conv2d_fused(x, w, *, stride: int = 1, padding: int = 0,
         window_strides=(stride, stride),
         padding=[(padding, padding), (padding, padding)],
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=lax.Precision.HIGHEST,
     )
     if scale is not None:
         out = out * scale.astype(accum_dtype)

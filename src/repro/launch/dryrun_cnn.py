@@ -71,7 +71,7 @@ def main():
     compiled = lowered.compile()
     dt = time.time() - t0
     print(compiled.memory_analysis())
-    print({k: v for k, v in rl.cost_analysis_dict(compiled).items()
+    print({k: v for k, v in compiled.cost_analysis().items()
            if k in ("flops", "bytes accessed")})
 
     colls = rl.parse_collectives(compiled.as_text(), default_group=chips)

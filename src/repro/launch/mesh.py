@@ -10,20 +10,12 @@ from __future__ import annotations
 import jax
 
 
-def shard_map_fn():
-    """The ``shard_map`` entry point across jax versions (pre-0.5 keeps it
-    in ``jax.experimental``).  Shared by the GxM executor and the
-    data-parallel training step."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the program places arrays by sharding annotations
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, model: int = 1, data: int | None = None):

@@ -11,17 +11,28 @@ every all-reduce / all-gather / reduce-scatter / all-to-all /
 collective-permute is the per-device shard; ring_factor(n) = 2(n-1)/n for
 all-reduce, (n-1)/n otherwise.
 
-Hardware model (TPU v5e target): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware model: the per-chip peaks of ``PEAKS``, keyed by the
+``device_kind`` JAX reports.  Every modeled table (cost models, dry runs,
+the CPU-run benches) prices ``MODEL_TARGET``, the TPU v5e; a path that
+measures a real device looks its peaks up with ``device_peaks`` and fails
+on a kind the table does not hold.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+# Published per-chip peaks (Google Cloud documentation, "TPU v5e"):
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of interchip interconnect
+# over four links (~50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+MODEL_TARGET = "TPU v5 lite"     # the chip every modeled table prices
+
+PEAK_FLOPS = PEAKS[MODEL_TARGET]["flops"]      # bf16 per chip
+HBM_BW = PEAKS[MODEL_TARGET]["hbm_bw"]         # bytes/s per chip
+ICI_BW = PEAKS[MODEL_TARGET]["ici_bw"]         # bytes/s per link
 STEP_OVERHEAD_S = 5e-7       # grid-step pipeline-fill overhead (one source
                              # of truth; repro.tune.measure re-exports in us)
 
@@ -36,6 +47,18 @@ CHAIN_ROOFLINE_KEYS = ("cost_s", "unfused_cost_s", "speedup", "flops",
                        "hbm_bytes", "unfused_hbm_bytes",
                        "intermediate_bytes", "launches", "efficiency",
                        "fused")
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Peaks of the chip ``jax.Device.device_kind`` names.  A kind missing
+    from ``PEAKS`` is an error, never a default: a measured path would
+    otherwise be priced against another chip."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r};"
+                       f" add them to PEAKS with their source") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -182,27 +205,21 @@ def composite_roofline(parts: list[dict], *, extra_hbm_bytes: float = 0.0,
     (materializing a dilated dO, re-interleaving phase outputs) at HBM
     bandwidth — traffic a zero-free plan avoids entirely.
     """
-    cost = extra_hbm_bytes / HBM_BW
-    flops = 0.0
-    hbm = extra_hbm_bytes
-    steps = 0
-    for t in parts:
-        roof = kernel_roofline(flops=t["flops"], hbm_bytes=t["hbm_bytes"],
-                               util=t.get("util", 1.0),
-                               n_steps=t.get("n_steps", 0),
-                               step_overhead_s=step_overhead_s)
-        cost += roof["cost_s"]
-        flops += t["flops"]
-        hbm += t["hbm_bytes"]
-        steps += t.get("n_steps", 0)
+    cost = extra_hbm_bytes / HBM_BW + sum(
+        kernel_roofline(flops=t["flops"], hbm_bytes=t["hbm_bytes"],
+                        util=t.get("util", 1.0), n_steps=t.get("n_steps", 0),
+                        step_overhead_s=step_overhead_s)["cost_s"]
+        for t in parts)
+    flops = sum(t["flops"] for t in parts)
     ideal = flops / PEAK_FLOPS
     return {
         "cost_s": cost,
         "flops": flops,
-        "hbm_bytes": hbm,
-        "n_steps": steps,
+        "hbm_bytes": extra_hbm_bytes + sum(t["hbm_bytes"] for t in parts),
+        "n_steps": sum(t.get("n_steps", 0) for t in parts),
         "launches": len(parts),
-        "efficiency": ideal / cost if cost > 0 else 0.0,
+        # ideal <= cost term by term; the sums may round past it by an ulp
+        "efficiency": min(ideal / cost, 1.0) if cost > 0 else 0.0,
     }
 
 
@@ -236,17 +253,8 @@ def chain_roofline(chain_t: dict, *,
     }
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions: older
-    releases return a one-element list of dicts, newer ones a flat dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def analyze(compiled, *, chips: int, model_flops_global: float) -> Roofline:
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     bytes_acc = float(ca.get("bytes accessed", 0.0))
     colls = parse_collectives(compiled.as_text(), default_group=chips)

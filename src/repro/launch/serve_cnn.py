@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.graph import GxM, inception_v3, resnet50
 from repro.graph.serving import CnnInferenceEngine, pick_bucket
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 
@@ -187,6 +188,7 @@ def main(argv=None):
     ap.add_argument("--deadline", type=float, default=6.0,
                     help="--fleet per-request deadline (simulated seconds)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     classes = args.classes or (10 if args.smoke else 1000)
     m, image = build_model(args.arch, smoke=args.smoke, num_classes=classes,

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.graph.executor import apply_bn_updates
-from repro.launch.mesh import data_axis_size, shard_map_fn
+from repro.launch.mesh import data_axis_size
 from repro.optim.compress import compressed_psum_tree, fold_residual
 
 
@@ -179,10 +179,9 @@ def make_cnn_train_step_dp(gxm, mesh, *, lr: float = 0.1,
     state_spec = {"params": P(), "step": P()}
     if compress == "int8":
         state_spec["residual"] = P(axis)
-    sharded = shard_map_fn()(dp_step, mesh=mesh,
-                             in_specs=(state_spec, P(axis)),
-                             out_specs=(state_spec, P()),
-                             check_rep=False)
+    sharded = jax.shard_map(dp_step, mesh=mesh,
+                            in_specs=(state_spec, P(axis)),
+                            out_specs=(state_spec, P()), check_vma=False)
     jitted = jax.jit(sharded)
 
     def step(state, batch):

@@ -7,7 +7,8 @@ Entries are keyed by everything that changes the winner:
   kind | shape params | dtype bytes | stride/padding | backend | device_kind
 
 and stored in a single versioned JSON file (default
-``~/.cache/repro_tune/blockings-v1.json``, override with ``REPRO_TUNE_CACHE``).
+``~/.cache/repro_tune/blockings-v<CACHE_VERSION>.json``, currently
+``blockings-v5.json``; override with ``REPRO_TUNE_CACHE``).
 Writes are atomic (tempfile + ``os.replace``) so concurrent benchmark runs
 never observe a torn file.  A version mismatch on load discards the file —
 bump ``CACHE_VERSION`` whenever the candidate space, the cost model, or the
@@ -22,7 +23,9 @@ import tempfile
 import threading
 import time
 
-CACHE_VERSION = 4     # v4: the "q8" int8-forward kind + its 1-byte-input
+CACHE_VERSION = 5     # v5: full-row tiles (RB_Q pinned to Q), feature
+                      #     blocks 128 or the whole dim, phase-plane bands
+                      # v4: the "q8" int8-forward kind + its 1-byte-input
                       #     working-set model (grow-to-budget rb_p)
                       # v3: tiled-wu space (c_blk/rb_q free, ceil-div rb_p)
                       #     + the "bwd" dual-conv kind

@@ -32,10 +32,11 @@ are meaningful even offline.
 from __future__ import annotations
 
 import math
+import sys
 
 from repro.core.blocking import LANE, ConvBlocking, MatmulBlocking
 from repro.launch.roofline import (HBM_BW, PEAK_FLOPS, STEP_OVERHEAD_S,
-                                   kernel_roofline)
+                                   device_peaks, kernel_roofline)
 from repro.tune.space import out_dim
 
 STEP_OVERHEAD_US = STEP_OVERHEAD_S * 1e6
@@ -466,7 +467,7 @@ def measure_conv_us(shape: dict, blk: ConvBlocking, *, kind: str = "fwd",
                          jnp.float32)
         fn = jax.jit(lambda x, do: conv2d_wu(
             x, do, stride=stride, padding=padding, filter_rs=(r, s),
-            b_p=blk.rb_p, k_blk=blk.k_blk, c_blk=blk.c_blk, rb_q=blk.rb_q,
+            b_p=blk.rb_p, k_blk=blk.k_blk, c_blk=blk.c_blk,
             whole_plane=False))
         wt = do
     elif kind == "q8":
@@ -474,14 +475,14 @@ def measure_conv_us(shape: dict, blk: ConvBlocking, *, kind: str = "fwd",
         x_q, w_q, sx, sw = quantize_conv_inputs(x, wt)
         fn = jax.jit(lambda x, wt: conv2d_q8(
             x, wt, x_scale=sx, w_scale=sw, stride=stride, padding=padding,
-            rb_p=blk.rb_p, k_blk=blk.k_blk, c_blk=blk.c_blk, rb_q=blk.rb_q,
+            rb_p=blk.rb_p, k_blk=blk.k_blk, c_blk=blk.c_blk,
             order=blk.order, whole_plane=False))
         x, wt = x_q, w_q
     else:                       # "fwd" and "bwd" (the dual IS a fwd launch)
         fn = jax.jit(lambda x, wt: conv2d_direct(
             x, wt, stride=stride, padding=padding, rb_p=blk.rb_p,
-            k_blk=blk.k_blk, c_blk=blk.c_blk, rb_q=blk.rb_q,
-            order=blk.order, whole_plane=False))
+            k_blk=blk.k_blk, c_blk=blk.c_blk, order=blk.order,
+            whole_plane=False))
 
     for _ in range(warmup):
         jax.block_until_ready(fn(x, wt))
@@ -500,19 +501,35 @@ def rank_conv(shape: dict, candidates: list[ConvBlocking], *,
     """Score candidates; returns (score_us, blocking) sorted best-first.
 
     Model scores everywhere; on TPU the model shortlists `measure_top`
-    candidates which are then re-ranked by real wall clock.
+    candidates which are then re-ranked by real wall clock.  A candidate
+    that fails to compile or run is counted and logged, and dropped from
+    the ranking; when none of the shortlist runs, this raises rather than
+    hand back the modeled order as if it had been measured.
     """
     scored = sorted(
         ((conv_cost_us(shape, b, minibatch=minibatch, kind=kind), b)
          for b in candidates), key=lambda t: t[0])
     if not can_measure(backend):
         return scored
-    timed = []
+    import jax
+    device_peaks(jax.devices()[0].device_kind)   # unknown chip: an error
+    timed, failed = [], []
     for _, b in scored[:measure_top]:
         try:
             timed.append((measure_conv_us(shape, b, kind=kind,
                                           minibatch=minibatch), b))
-        except Exception:  # noqa: BLE001 — candidate failed to compile
-            continue
+        except Exception as e:  # noqa: BLE001 — compile/run failure
+            failed.append((b, e))
+            print(f"rank_conv: {kind} {shape} candidate {b} failed: "
+                  f"{type(e).__name__}: {str(e).splitlines()[0][:200]}",
+                  file=sys.stderr)
+    if failed:
+        print(f"rank_conv: {len(failed)} of {len(failed) + len(timed)} "
+              f"measured candidates failed for {kind} {shape}",
+              file=sys.stderr)
+    if not timed:
+        raise RuntimeError(
+            f"rank_conv: no {kind} candidate compiled on the chip for "
+            f"{shape}") from (failed[-1][1] if failed else None)
     timed.sort(key=lambda t: t[0])
-    return timed or scored
+    return timed

@@ -3,29 +3,32 @@
 For a conv layer the tunable coordinates are exactly the knobs the Pallas
 kernels expose:
 
-  rb_p   output rows per microkernel (paper RB_P; MXU M-tile = rb_p*rb_q)
-  rb_q   output cols per microkernel (paper RB_Q; fwd/bwd/wu, 0/q = full row)
+  rb_p   output rows per microkernel (paper RB_P; MXU M-tile = rb_p*Q —
+         the tiled kernels always compute full output rows, so RB_Q is
+         pinned to Q and is no coordinate)
   k_blk  output-feature block (paper K_b; MXU N-tile, must divide K)
   c_blk  input-feature block (paper C_b accumulation; must divide C)
   order  grid/dryrun loop order over (N, K_b, P_b, C_b) (paper §II-C)
+
+Feature blocks are the ones Mosaic accepts on the lane dim: 128 where it
+divides the dim, else the whole dim.
 
 ``conv_candidates`` enumerates the feasible cross product — VMEM-budget
 filtered, lane-aligned, divisibility-respecting — with the analytic heuristic
 first, so it is both the cost-model prior and the seed the search can never
 do worse than.  Kinds:
 
-  "fwd"     conv2d_direct tiled forward: all five coordinates free (C-block
-            accumulation + RB_Q column blocking + grid loop order)
+  "fwd"     conv2d_direct tiled forward: all four coordinates free (C-block
+            accumulation + grid loop order)
   "bwd"     the backward-data dual conv — the same tiled forward kernel run
-            on the transformed (dO, W') problem, so the same five coordinates
+            on the transformed (dO, W') problem, so the same four coordinates
             are free; a separate kind so dual-shape winners get their own
             cache namespace (shapes come from ``duality.dual_conv_signatures``)
   "wu"      conv2d_wu band-streamed update pass: rb_p ceil-div (tails are
-            masked in-kernel, no divisor constraint), c_blk / rb_q free; the
-            grid order is fixed (K_b, C_b, N, P_b, Q_b), so order is not a
-            coordinate
+            zero-padded, no divisor constraint), c_blk free; the grid order
+            is fixed (K_b, C_b, N, P_b), so order is not a coordinate
   "streams" conv2d_streams: rb_p/k_blk/c_blk/order free; whole-plane
-  "q8"      conv2d_q8 tiled int8 forward: the same five coordinates as
+  "q8"      conv2d_q8 tiled int8 forward: the same four coordinates as
             "fwd" but priced at 1 byte/element input-side (pass
             ``dtype_bytes=1``) — the 4x-smaller band admits taller rb_p
             under the same budget, so its candidate pool is genuinely
@@ -33,8 +36,8 @@ do worse than.  Kinds:
 """
 from __future__ import annotations
 
-from repro.core.blocking import (LANE, SUBLANE, VMEM_BUDGET, ConvBlocking,
-                                 MatmulBlocking, conv_blocking_analytic,
+from repro.core.blocking import (VMEM_BUDGET, ConvBlocking, MatmulBlocking,
+                                 aligned_block, conv_blocking_analytic,
                                  conv_working_set, divisors,
                                  matmul_blocking_analytic)
 
@@ -44,12 +47,6 @@ MAX_CANDIDATES = 128
 
 def out_dim(h: int, r: int, stride: int, padding: int) -> int:
     return (h + 2 * padding - r) // stride + 1
-
-
-def _feature_blocks(dim: int) -> list[int]:
-    """Divisors of `dim` that are sublane-aligned and at most one MXU tile."""
-    blocks = [d for d in divisors(dim) if d % SUBLANE == 0 and d <= LANE]
-    return blocks or [dim]          # tiny dims: single un-aligned block
 
 
 def _rb_candidates(p: int, *, require_divisor: bool) -> list[int]:
@@ -70,12 +67,6 @@ def _rb_candidates(p: int, *, require_divisor: bool) -> list[int]:
     return cands
 
 
-def _rb_q_candidates(q: int) -> list[int]:
-    """RB_Q column blocks: the full row plus a few power-of-two column
-    blocks for wide images (the ceil-div Q grid masks the tail)."""
-    return sorted({q} | {b for b in (8, 16, 32, 64, 128) if b < q})
-
-
 def conv_candidates(*, h: int, w: int, c: int, k: int, r: int, s: int,
                     stride: int, padding: int, dtype_bytes: int = 4,
                     kind: str = "fwd",
@@ -90,51 +81,45 @@ def conv_candidates(*, h: int, w: int, c: int, k: int, r: int, s: int,
         dtype_bytes=dtype_bytes, vmem_budget=vmem_budget,
         whole_plane=(True if whole else None), kind=kind)
 
-    k_blocks = _feature_blocks(k)
+    k_blocks = [aligned_block(k)]
     if kind == "wu":
-        # band-streamed update pass: c_blk / rb_q free, grid order fixed
-        c_blocks = sorted({c} | set(_feature_blocks(c)), reverse=True)
+        # band-streamed update pass: c_blk free, grid order fixed
+        c_blocks = sorted({c, aligned_block(c)}, reverse=True)
         orders = (seed.order,)
-        rb_qs = _rb_q_candidates(max(q, 1))
     elif kind == "streams":
-        c_blocks = _feature_blocks(c)
+        c_blocks = [aligned_block(c)]
         orders = ORDERS
-        rb_qs = [q]
     else:
-        # fwd/bwd/q8: full-C single-pass first, then lane-aligned C_b blocks
-        c_blocks = sorted({c} | set(_feature_blocks(c)), reverse=True)
+        # fwd/bwd/q8: full-C single-pass first, then the lane-tile C_b block
+        c_blocks = sorted({c, aligned_block(c)}, reverse=True)
         orders = ORDERS
-        rb_qs = _rb_q_candidates(max(q, 1))
     rbs = _rb_candidates(max(p, 1), require_divisor=False)
     ws_kind = kind if kind in ("wu", "q8") else "fwd"
 
     pool: list[ConvBlocking] = []
-    seen = {(seed.rb_p, seed.k_blk, seed.c_blk, seed.order,
-             seed.rb_q or q)}
+    seen = {(seed.rb_p, seed.k_blk, seed.c_blk, seed.order)}
     for rb in rbs:
         for kb in k_blocks:
             for cb in c_blocks:
-                for rq in rb_qs:
-                    ws = conv_working_set(
-                        h=h, w=w, c=c, k_blk=kb, r=r, s=s, q=q, rb_p=rb,
-                        padding=padding, dtype_bytes=dtype_bytes,
-                        stride=stride, c_blk=cb, rb_q=rq,
-                        whole_plane=whole, kind=ws_kind)
-                    if ws > vmem_budget:
+                ws = conv_working_set(
+                    h=h, w=w, c=c, k_blk=kb, r=r, s=s, q=q, rb_p=rb,
+                    padding=padding, dtype_bytes=dtype_bytes, stride=stride,
+                    c_blk=cb, whole_plane=whole, kind=ws_kind)
+                if ws > vmem_budget:
+                    continue
+                for order in orders:
+                    key = (rb, kb, cb, order)
+                    if key in seen:
                         continue
-                    for order in orders:
-                        key = (rb, kb, cb, order, rq)
-                        if key in seen:
-                            continue
-                        seen.add(key)
-                        pool.append(ConvBlocking(rb_p=rb, k_blk=kb, c_blk=cb,
-                                                 order=order, vmem_bytes=ws,
-                                                 rb_q=rq))
+                    seen.add(key)
+                    pool.append(ConvBlocking(rb_p=rb, k_blk=kb, c_blk=cb,
+                                             order=order, vmem_bytes=ws,
+                                             rb_q=q))
     if len(pool) > MAX_CANDIDATES - 1:
         # spread-sample the (rb_p-major) pool instead of truncating its
         # prefix: a prefix cut would exhaust the budget inside the first
-        # rb_p value's c_blk x rb_q x order cross product and never explore
-        # the register-block axis at all
+        # rb_p value's c_blk x order cross product and never explore the
+        # register-block axis at all
         step = len(pool) / (MAX_CANDIDATES - 1)
         pool = [pool[int(i * step)] for i in range(MAX_CANDIDATES - 1)]
     return [seed] + pool

@@ -21,6 +21,11 @@ def run_sub(body: str) -> str:
         import jax, jax.numpy as jnp
         import numpy as np
         assert len(jax.devices()) == 8
+
+        def auto_mesh(shape, axes):
+            # the program shards by annotation: Auto axes, not Explicit
+            return jax.make_mesh(shape, axes, axis_types=(
+                jax.sharding.AxisType.Auto,) * len(axes))
     """) % os.path.join(REPO, "src") + textwrap.dedent(body)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=420)
@@ -38,8 +43,8 @@ def test_sharded_train_step_matches_single_device():
         cfg = smoke_config(get_config("qwen2-1.5b"))
         data = SyntheticLMData(vocab=cfg.vocab, seq_len=16, global_batch=8)
 
-        mesh1 = jax.make_mesh((1, 1), ("data", "model"))
-        mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh1 = auto_mesh((1, 1), ("data", "model"))
+        mesh8 = auto_mesh((4, 2), ("data", "model"))
         losses = {}
         for name, mesh in (("single", mesh1), ("sharded", mesh8)):
             state, step = build(cfg, mesh, lr=1e-2)
@@ -73,13 +78,10 @@ def test_compressed_psum_error_feedback():
         from jax.sharding import PartitionSpec as P
         from repro.optim.compress import compressed_psum
         import jax, jax.numpy as jnp, numpy as np
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # pre-0.5 jax keeps it in experimental
-            from jax.experimental.shard_map import shard_map
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = auto_mesh((8,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
-        @partial(shard_map, mesh=mesh, in_specs=P("data"),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
                  out_specs=P("data"))
         def allreduce_q(gs):
             out, resid = compressed_psum(gs[0], "data")
@@ -109,14 +111,14 @@ def test_elastic_reshard_across_meshes(tmp_path):
         data = SyntheticLMData(vocab=cfg.vocab, seq_len=8, global_batch=8)
 
         # train 2 steps on a (2,4) mesh, checkpoint
-        meshA = jax.make_mesh((2, 4), ("data", "model"))
+        meshA = auto_mesh((2, 4), ("data", "model"))
         state, step = build(cfg, meshA, lr=1e-2)
         for i in range(2):
             state, _ = step(state, data.batch_at(i))
         C.save({str(tmp_path)!r}, 2, state)
 
         # restore onto a (8,1) mesh — different DP/TP split — and continue
-        meshB = jax.make_mesh((8, 1), ("data", "model"))
+        meshB = auto_mesh((8, 1), ("data", "model"))
         stateB, stepB = build(cfg, meshB, lr=1e-2)
         shardingsB = jax.tree.map(lambda x: x.sharding, stateB)
         restored = elastic_reshard({str(tmp_path)!r}, 2, stateB, shardingsB)

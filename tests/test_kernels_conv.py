@@ -1,8 +1,8 @@
 """Per-kernel allclose vs the pure-jnp oracle (interpret mode), swept over
 shapes / strides / dtypes, plus hypothesis property sweeps.
 
-The forward kernel runs *tiled* by default (row-band streaming, C_b
-accumulation, RB_Q column blocks — DESIGN.md §9); the legacy whole-plane
+The forward kernel runs *tiled* by default (full-row band streaming over
+stride-phase planes, C_b accumulation — DESIGN.md §9); the legacy whole-plane
 variant is pinned explicitly so both input strategies stay bit-exact."""
 import jax
 import jax.numpy as jnp
@@ -120,27 +120,27 @@ def test_conv2d_direct_property(n, hw, c, k, r, stride, rb_p, seed):
                                rtol=1e-3, atol=1e-3)
 
 
-# -- tiled-input path (row-band streaming, C_b accumulation, RB_Q) -----------
+# -- tiled-input path (row-band streaming, C_b accumulation) ----------------
 
 TILED_CASES = [
-    # n, h, w, c, k, r, stride, pad, rb_p, rb_q, c_blk, order
-    (2, 8, 8, 16, 16, 3, 1, 1, 4, None, 8, "nkpc"),   # C_b accumulation
-    (1, 9, 9, 8, 16, 3, 1, 1, 4, 4, 8, "npkc"),       # P and Q ceil-div tails
-    (2, 16, 16, 8, 8, 3, 2, 1, 4, 3, 8, "knpc"),      # stride 2 + Q tail
-    (1, 14, 14, 16, 32, 1, 1, 0, 7, 5, 8, "pknc"),    # 1x1, every axis free
-    (1, 12, 12, 8, 8, 5, 1, 2, 3, 6, 8, "nkpc"),      # 5x5 halo
-    (1, 24, 24, 8, 16, 7, 2, 3, 4, 6, 8, "npkc"),     # 7x7 stride-2 halo
+    # n, h, w, c, k, r, stride, pad, rb_p, c_blk, order
+    (2, 8, 8, 16, 16, 3, 1, 1, 4, 8, "nkpc"),     # C_b accumulation
+    (1, 9, 9, 8, 16, 3, 1, 1, 4, 8, "npkc"),      # P tail, Q off the tile
+    (2, 16, 16, 8, 8, 3, 2, 1, 4, 8, "knpc"),     # stride 2 phase planes
+    (1, 14, 14, 16, 32, 1, 1, 0, 7, 8, "pknc"),   # 1x1, every axis free
+    (1, 12, 12, 8, 8, 5, 1, 2, 3, 8, "nkpc"),     # 5x5 halo
+    (1, 24, 24, 8, 16, 7, 2, 3, 4, 8, "npkc"),    # 7x7 stride-2 halo
 ]
 
 
 @pytest.mark.parametrize("case", TILED_CASES)
 def test_conv2d_tiled_blocking_sweep(rng, case):
-    """Every freed axis — c_blk, rb_q, loop order — stays bit-exact vs the
-    oracle, including the ceil-div spatial tails."""
-    n, h, w, c, k, r, stride, pad, rb_p, rb_q, c_blk, order = case
+    """Every freed axis — c_blk, loop order — stays bit-exact vs the
+    oracle, including the ceil-div row tail and rows off the sublane tile."""
+    n, h, w, c, k, r, stride, pad, rb_p, c_blk, order = case
     x, wt = _data(rng, n, h, w, c, k, r)
     out = conv2d_direct(x, wt, stride=stride, padding=pad, rb_p=rb_p,
-                        rb_q=rb_q, c_blk=c_blk, order=order, interpret=True)
+                        c_blk=c_blk, order=order, interpret=True)
     exp = ref.conv2d(x, wt, stride=stride, padding=pad)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=1e-4, atol=1e-4)
@@ -148,7 +148,7 @@ def test_conv2d_tiled_blocking_sweep(rng, case):
 
 def test_conv2d_tail_with_fused_residual(rng):
     """Ceil-div P tail + full fused epilogue: the residual BlockSpec reads a
-    (1, rb_p, rb_q, k_blk) block at the tail, so p % rb_p != 0 with
+    (1, rb_p, Q', k_blk) block at the tail, so p % rb_p != 0 with
     relu+residual must stay bit-exact (pallas masks the out-of-range rows)."""
     n, h, c, k, r, pad = 1, 9, 8, 16, 3, 1
     rb_p = 4                                    # p = 9 -> tail block of 1
@@ -158,8 +158,8 @@ def test_conv2d_tail_with_fused_residual(rng):
     exp = ref.conv2d_fused(x, wt, stride=1, padding=pad, bias=b,
                            residual=res, relu=True)
     for kwargs in (dict(),                          # C unblocked, full row
-                   dict(c_blk=8, rb_q=4),           # C_b passes + Q tail
-                   dict(c_blk=8, rb_q=4, order="npkc")):
+                   dict(c_blk=8),                   # C_b passes
+                   dict(c_blk=8, order="npkc")):
         out = conv2d_direct(x, wt, stride=1, padding=pad, bias=b,
                             residual=res, relu=True, rb_p=rb_p,
                             interpret=True, **kwargs)
@@ -208,38 +208,37 @@ def test_resnet_stem_tiled_regression(rng):
 
     x, wt = _data(rng, sh["n"], sh["h"], sh["w"], sh["c"], sh["k"], sh["r"])
     out = conv2d_direct(x, wt, stride=sh["stride"], padding=sh["padding"],
-                        rb_p=blk.rb_p, rb_q=16, c_blk=sh["c"],
-                        interpret=True)
+                        rb_p=blk.rb_p, c_blk=sh["c"], interpret=True)
     exp = ref.conv2d(x, wt, stride=sh["stride"], padding=sh["padding"])
     assert out.shape == (1, 112, 112, sh["k"])
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=1e-3, atol=1e-3)
 
 
-# -- tiled update pass (band streaming, C/Q blocking, ceil-div tails) --------
+# -- tiled update pass (band streaming, C blocking, ceil-div tails) ----------
 
 TILED_WU_CASES = [
-    # n, h, w, c, k, r, stride, pad, b_p, rb_q, c_blk
-    (2, 8, 8, 16, 16, 3, 1, 1, 4, None, 8),    # C_b accumulation
-    (2, 9, 9, 8, 16, 3, 1, 1, 4, 4, 8),        # P and Q ceil-div tails
-    (1, 16, 16, 16, 8, 3, 2, 1, 3, 5, 8),      # stride 2 + non-divisor tails
-    (1, 12, 12, 8, 8, 5, 1, 2, 5, 6, None),    # 5x5 halo + tails
-    (1, 24, 24, 8, 16, 7, 2, 3, 4, 6, 8),      # 7x7 stride-2 halo
-    (1, 14, 14, 16, 32, 1, 1, 0, 7, 5, 8),     # 1x1, every axis free
+    # n, h, w, c, k, r, stride, pad, b_p, c_blk
+    (2, 8, 8, 16, 16, 3, 1, 1, 4, 8),      # C_b accumulation
+    (2, 9, 9, 8, 16, 3, 1, 1, 4, 8),       # P tail, Q off the tile
+    (1, 16, 16, 16, 8, 3, 2, 1, 3, 8),     # stride 2 + non-divisor tail
+    (1, 12, 12, 8, 8, 5, 1, 2, 5, None),   # 5x5 halo + tail
+    (1, 24, 24, 8, 16, 7, 2, 3, 4, 8),     # 7x7 stride-2 halo
+    (1, 14, 14, 16, 32, 1, 1, 0, 7, 8),    # 1x1, every axis free
 ]
 
 
 @pytest.mark.parametrize("case", TILED_WU_CASES)
 def test_conv2d_wu_tiled_blocking_sweep(rng, case):
-    """The band-streamed update pass: every freed axis — c_blk, rb_q, and
-    ceil-div P/Q tails (masked in-kernel) — stays correct vs the VJP
+    """The band-streamed update pass: every freed axis — c_blk and the
+    ceil-div P tail over a zero-padded dO — stays correct vs the VJP
     oracle.  No divisibility of P is required any more."""
-    n, h, w, c, k, r, stride, pad, bp, rq, cb = case
+    n, h, w, c, k, r, stride, pad, bp, cb = case
     x, _ = _data(rng, n, h, w, c, k, r)
     p = (h + 2 * pad - r) // stride + 1
     do = jnp.asarray(rng.standard_normal((n, p, p, k)), jnp.float32)
     out = conv2d_wu(x, do, stride=stride, padding=pad, filter_rs=(r, r),
-                    b_p=bp, rb_q=rq, c_blk=cb, whole_plane=False,
+                    b_p=bp, c_blk=cb, whole_plane=False,
                     interpret=True)
     exp = ref.conv2d_bwd_weights(x, do, stride=stride, padding=pad,
                                  filter_rs=(r, r))
@@ -290,7 +289,7 @@ def test_wu_stem_tiled_regression(rng):
     do = jnp.asarray(rng.standard_normal((sh["n"], p, p, sh["k"])),
                      jnp.float32)
     out = conv2d_wu(x, do, stride=sh["stride"], padding=sh["padding"],
-                    filter_rs=(sh["r"], sh["s"]), b_p=blk.rb_p, rb_q=16,
+                    filter_rs=(sh["r"], sh["s"]), b_p=blk.rb_p,
                     c_blk=sh["c"], whole_plane=False, interpret=True)
     exp = ref.conv2d_bwd_weights(x, do, stride=sh["stride"],
                                  padding=sh["padding"],
@@ -301,20 +300,19 @@ def test_wu_stem_tiled_regression(rng):
 
 
 def test_pad_input_no_overpad_stride2():
-    """pad_input must stop at the last row/col the grid can touch: for
-    stride > 1 the symmetric bottom pad used to inflate the plane past it."""
+    """pad_input must stop at the last row the grid can touch: for stride > 1
+    the symmetric bottom pad used to inflate the plane past it."""
     h = w = p = 12
     r, stride, padding = 3, 2, 1
     p_out = (h + 2 * padding - r) // stride + 1           # 6
     for rb_p in (2, 3, 6):                                 # rb_p | p cases
         x = jnp.zeros((1, h, w, 8), jnp.float32)
-        q = p_out
         xp = pad_input(x, padding=padding, stride=stride, rb_p=rb_p, r=r,
-                       p=p_out, rb_q=q, s=r, q=q)
+                       p=p_out)
         rows_needed = (int(np.ceil(p_out / rb_p)) * rb_p - 1) * stride + r
         assert xp.shape[1] == max(rows_needed, h + padding)
         assert xp.shape[1] < h + 2 * padding              # strictly tighter
     # ceil-div tail still covered: rb_p = 4 -> 2 blocks of 4 rows over p=6
     xp = pad_input(jnp.zeros((1, h, w, 8), jnp.float32), padding=padding,
-                   stride=stride, rb_p=4, r=r, p=p_out, rb_q=4, s=r, q=p_out)
+                   stride=stride, rb_p=4, r=r, p=p_out)
     assert xp.shape[1] >= (2 * 4 - 1) * stride + r

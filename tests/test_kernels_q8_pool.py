@@ -67,12 +67,11 @@ def test_conv2d_q8_relu_epilogue(rng):
 
 TILED_Q8_CASES = [
     # n, h, w, c, k, r, stride, pad, blocking kwargs
-    (2, 12, 12, 16, 16, 3, 1, 1, dict(rb_p=5, rb_q=5, c_blk=8)),
-    (1, 13, 13, 8, 16, 3, 2, 1, dict(rb_p=3, rb_q=4, k_blk=8)),
-    (1, 11, 11, 8, 24, 1, 1, 0, dict(rb_p=4, rb_q=3, k_blk=8)),
-    (1, 24, 24, 8, 16, 7, 2, 3, dict(rb_p=4, rb_q=6, c_blk=8)),
-    (1, 10, 10, 16, 8, 3, 1, 1, dict(rb_p=4, rb_q=10, c_blk=8,
-                                     order="npkc")),
+    (2, 12, 12, 16, 16, 3, 1, 1, dict(rb_p=5, c_blk=8)),
+    (1, 13, 13, 8, 16, 3, 2, 1, dict(rb_p=3, k_blk=8)),
+    (1, 11, 11, 8, 24, 1, 1, 0, dict(rb_p=4, k_blk=8)),
+    (1, 24, 24, 8, 16, 7, 2, 3, dict(rb_p=4, c_blk=8)),
+    (1, 10, 10, 16, 8, 3, 1, 1, dict(rb_p=4, c_blk=8, order="npkc")),
 ]
 
 
@@ -158,7 +157,7 @@ def test_q8_stem_tiled_under_pressure_budget(rng):
         (sh["r"], sh["s"], sh["c"], sh["k"])) * 0.1, jnp.float32)
     xq, wq, sx, sw = quantize_conv_inputs(x, wt)
     out = conv2d_q8(xq, wq, x_scale=sx, w_scale=sw, stride=sh["stride"],
-                    padding=sh["padding"], rb_p=blk.rb_p, rb_q=16,
+                    padding=sh["padding"], rb_p=blk.rb_p,
                     c_blk=sh["c"], whole_plane=False, interpret=True)
     exp = np.asarray(ref.conv2d(x, wt, stride=sh["stride"],
                                 padding=sh["padding"]))

@@ -19,6 +19,8 @@ from hypothesis import given, settings, strategies as st
 from repro.core.quantize import dequantize, quantize_act, quantize_int8
 
 SCALE_GUARD = 1e-12      # the shared guard every scale in core.quantize adds
+# No per-example deadline: each new shape compiles the quantizer, so an
+# example's time is JAX's compile time, not the property's.
 
 
 def _vals(seed: int, n: int, scale_pow: int) -> np.ndarray:
@@ -26,7 +28,7 @@ def _vals(seed: int, n: int, scale_pow: int) -> np.ndarray:
     return (rng.standard_normal(n) * 10.0 ** scale_pow).astype(np.float32)
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 2 ** 31 - 1), n=st.integers(1, 64),
        scale_pow=st.integers(-3, 3))
 def test_act_roundtrip_error_at_most_half_scale(seed, n, scale_pow):
@@ -39,7 +41,7 @@ def test_act_roundtrip_error_at_most_half_scale(seed, n, scale_pow):
         float(np.max(np.abs(x - deq)) / scale)
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 2 ** 31 - 1), n=st.integers(1, 64),
        blowup=st.floats(1.0, 100.0))
 def test_act_clips_symmetrically_at_127(seed, n, blowup):
@@ -56,7 +58,7 @@ def test_act_clips_symmetrically_at_127(seed, n, blowup):
     np.testing.assert_array_equal(q_neg, -q)
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 2 ** 31 - 1), rows=st.integers(1, 7),
        cols=st.integers(1, 8))
 def test_small_tensors_pass_through_unquantized(seed, rows, cols):
@@ -73,7 +75,7 @@ def test_small_tensors_pass_through_unquantized(seed, rows, cols):
         assert set(out["w"]) == {"q", "s"}           # big enough: quantized
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 2 ** 31 - 1), rows=st.integers(8, 32),
        cols=st.integers(8, 32), scale_pow=st.integers(-6, 3))
 def test_weight_scales_strictly_positive(seed, rows, cols, scale_pow):

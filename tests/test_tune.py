@@ -132,16 +132,17 @@ def test_candidates_respect_constraints():
 
 
 def test_wu_candidates_free_cblk_rbq_and_tails():
-    """The tiled update pass freed the wu space: rb_p is ceil-div (tails are
-    masked in-kernel, so non-divisors of P are legal candidates) and
-    c_blk / rb_q are search coordinates — all within the VMEM budget under
-    the band-based wu residency model."""
+    """The tiled update pass freed the wu space: rb_p is ceil-div (dO is
+    zero-padded, so non-divisors of P are legal candidates) and c_blk is a
+    search coordinate, while every tile is a full row (rb_q = Q: the kernel
+    streams full-width bands) — all within the VMEM budget under the
+    band-based wu residency model."""
     p = 14
     cands = tune.conv_candidates(h=14, w=14, c=256, k=256, r=3, s=3,
                                  stride=1, padding=1, kind="wu")
     assert any(p % b.rb_p for b in cands)               # non-divisor rb_p
     assert len({b.c_blk for b in cands}) > 1            # C_b freed
-    assert len({b.rb_q or p for b in cands}) > 1        # RB_Q freed
+    assert {b.rb_q or p for b in cands} == {p}          # full rows only
     from repro.core.blocking import conv_working_set
     for b in cands:
         assert 256 % b.c_blk == 0 and 256 % b.k_blk == 0
