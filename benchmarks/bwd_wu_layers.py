@@ -66,10 +66,9 @@ def _wu_variant(shape: dict, blk, *, whole: bool) -> dict:
         h=shape["h"], w=shape["w"], c=shape["c"], k_blk=blk.k_blk,
         r=shape["r"], s=shape["s"], q=q, rb_p=blk.rb_p,
         padding=shape["padding"], stride=shape["stride"],
-        c_blk=None if whole else blk.c_blk, rb_q=None if whole else blk.rb_q,
-        whole_plane=whole, kind="wu")
+        c_blk=None if whole else blk.c_blk, whole_plane=whole, kind="wu")
     return {
-        "blocking": {"rb_p": blk.rb_p, "rb_q": 0 if whole else blk.rb_q,
+        "blocking": {"rb_p": blk.rb_p,
                      "k_blk": blk.k_blk, "c_blk": shape["c"] if whole
                      else blk.c_blk},
         "cost_us": round(roof["cost_s"] * 1e6, 3),

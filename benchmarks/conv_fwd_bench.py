@@ -5,7 +5,7 @@ roofline efficiency for the ResNet-50 (paper Table I) and Inception-v3
 conv tables, under the *same* per-shape blocking, for both forward input
 strategies:
 
-  tiled   row-band streaming + C_b accumulation + RB_Q (the default kernel)
+  tiled   row-band streaming + C_b accumulation (the default kernel)
   whole   the legacy whole-plane kernel (input plane shipped per grid step)
 
 Numbers come from the schedule-resolved roofline model
@@ -60,8 +60,7 @@ def _variant(shape: dict, blk, *, whole: bool) -> dict:
         h=shape["h"], w=shape["w"], c=shape["c"], k_blk=blk.k_blk,
         r=shape["r"], s=shape["s"], q=q, rb_p=blk.rb_p,
         padding=shape["padding"], stride=shape["stride"],
-        c_blk=None if whole else blk.c_blk, rb_q=None if whole else blk.rb_q,
-        whole_plane=whole)
+        c_blk=None if whole else blk.c_blk, whole_plane=whole)
     return {
         "cost_us": round(roof["cost_s"] * 1e6, 3),
         "images_per_sec": round(MINIBATCH / roof["cost_s"], 1),
@@ -85,7 +84,7 @@ def layer_record(shape: dict, *, measure: bool = False) -> dict:
         "shape": {f: shape[f] for f in ("h", "w", "c", "k", "r", "s",
                                         "stride", "padding")},
         "path": "direct" if lane_ok(shape["c"], shape["k"]) else "im2col",
-        "blocking": {"rb_p": blk.rb_p, "rb_q": blk.rb_q, "k_blk": blk.k_blk,
+        "blocking": {"rb_p": blk.rb_p, "k_blk": blk.k_blk,
                      "c_blk": blk.c_blk, "order": blk.order},
         "tiled": _variant(shape, blk, whole=False),
         "whole_plane": _variant(shape, blk, whole=True),

@@ -80,8 +80,8 @@ def _q8_variant(args: dict, minibatch: int, *, kind: str,
     vmem = conv_working_set(
         h=args["h"], w=args["w"], c=args["c"], k_blk=blk.k_blk, r=args["r"],
         s=args["s"], q=q, rb_p=blk.rb_p, padding=args["padding"],
-        stride=args["stride"], c_blk=blk.c_blk, rb_q=blk.rb_q,
-        dtype_bytes=dtype_bytes, kind=kind)
+        stride=args["stride"], c_blk=blk.c_blk, dtype_bytes=dtype_bytes,
+        kind=kind)
     rec = {
         "cost_us": round(roof["cost_s"] * 1e6, 3),
         "hbm_bytes": int(t["hbm_bytes"]),

@@ -1,4 +1,4 @@
-"""Blocking selection — the paper's §II-B/C/D RB_P/RB_Q/cache-block choice,
+"""Blocking selection — the paper's §II-B/C/D RB_P/cache-block choice,
 re-derived for the TPU memory hierarchy (HBM -> VMEM -> VREG, MXU 128x128).
 
 The paper picks register blocks to (a) hide FMA latency with independent
@@ -40,14 +40,12 @@ MXU = 128
 
 @dataclasses.dataclass(frozen=True)
 class ConvBlocking:
-    rb_p: int          # output rows per microkernel (paper RB_P)
+    rb_p: int          # output rows per microkernel (paper RB_P; each a
+                       # full output row — RB_Q is always Q on the TPU)
     k_blk: int         # output-feature block (paper's K_b vector block)
     c_blk: int         # input-feature block (C_b accumulation passes)
     order: str         # grid/dryrun loop order (paper §II-C)
     vmem_bytes: int    # modeled working set
-    rb_q: int = 0      # output cols per microkernel (paper RB_Q; 0 = full Q)
-                       # — priced by the VMEM/traffic models only: the tiled
-                       # kernels always compute full rows
 
 
 def divisors(x: int):
@@ -62,19 +60,58 @@ def aligned_block(dim: int) -> int:
     return LANE if dim % LANE == 0 else dim
 
 
+def tiled_conv_buffers(*, q: int, r: int, s: int, stride: int, rb_p: int,
+                       c_blk: int, k_blk: int, dtype_bytes: int = 4,
+                       kind: str = "fwd", cols: int | None = None):
+    """(blocks, scratch) of one tiled conv kernel, as (shape, itemsize)
+    pairs — the buffers the kernels in ``repro.kernels`` declare, without
+    the optional epilogue operands.
+
+    The input block is a halo'd row band of the stride-phase planes: ``rb_p
+    + (r-1)//stride`` rows x min(stride, r)·min(stride, s) planes x the
+    full row (``cols`` + (s-1)//stride) x ``c_blk``.  "fwd" (and "bwd",
+    the dual forward launch) add the weight block, the output tile and the
+    f32 accumulator scratch; "q8" streams int8 band and weights
+    (``dtype_bytes=1``) with an f32 dequant row, f32 output tile and int32
+    accumulator; "wu" adds the dO tile and the revisited f32 dW tile.
+    ``cols`` is the output row width: by default the kernels' Q' (Q rounded
+    up to the sublane tile, ``tile_cols``); Q counts logical pixels."""
+    if cols is None:
+        from repro.kernels.conv2d_direct import tile_cols
+        cols = tile_cols(q, dtype_bytes)
+    band = (1, rb_p + (r - 1) // stride, min(stride, r), min(stride, s),
+            cols + (s - 1) // stride, c_blk)
+    tile = (1, rb_p, cols, k_blk)
+    if kind == "wu":
+        return [(band, dtype_bytes), (tile, dtype_bytes),
+                ((r, s, c_blk, k_blk), 4)], []
+    acc = [((rb_p * cols, k_blk), 4)]
+    if kind == "q8":
+        return [(band, 1), ((r, s, c_blk, k_blk), 1), ((1, k_blk), 4),
+                (tile, 4)], acc
+    return [(band, dtype_bytes), ((r, s, c_blk, k_blk), dtype_bytes),
+            (tile, dtype_bytes)], acc
+
+
 def conv_working_set(*, h: int, w: int, c: int, k_blk: int, r: int, s: int,
                      q: int, rb_p: int, padding: int, dtype_bytes: int = 4,
                      stride: int = 1, c_blk: int | None = None,
-                     rb_q: int | None = None,
                      whole_plane: bool = False,
                      kind: str = "fwd") -> int:
     """Modeled per-grid-step VMEM bytes for a conv blocking candidate.
 
-    Tiled (default): the input contribution is one streamed row band —
-    ``((rb_p-1)*stride + r) x ((rb_q-1)*stride + s) x c_blk`` — so the
-    working set is independent of H*W.  ``whole_plane=True`` models the
-    legacy kernels (fwd whole-plane variant, legacy wu, q8, streams) that
-    keep the full padded plane resident; there it scales with H*W*c_blk.
+    Tiled (default): the buffers the tiled kernel declares
+    (``tiled_conv_buffers``: a full-width row band of the stride-phase
+    planes, so the working set is independent of H), one copy each, in
+    logical pixels — rows of Q, not the kernels' sublane-padded Q' (the
+    traffic model, ``tune.measure.conv_traffic``, counts the same way).
+    The kernels ask Mosaic for those buffers at Q', double-buffered and
+    padded to the (8, 128) tile, plus headroom
+    (``kernels.conv2d_direct.compiler_params``), so the budget is a target
+    for the blocking, not the scoped-VMEM limit.  ``whole_plane=True``
+    models the legacy kernels (fwd whole-plane variant, legacy wu, q8,
+    streams) that keep the full padded plane resident; there it scales with
+    H*W*c_blk.
 
     ``kind`` picks the residency model: "fwd"/"bwd" (the forward kernel —
     the bwd-data dual *is* a forward launch) hold a weight block and an
@@ -86,22 +123,22 @@ def conv_working_set(*, h: int, w: int, c: int, k_blk: int, r: int, s: int,
     the output side does not.
     """
     c_blk = c if not c_blk else c_blk
-    rb_q = q if not rb_q else rb_q
-    if whole_plane:
-        hp, wp = h + 2 * padding + r, w + 2 * padding   # padded upper bound
-        x_bytes = hp * wp * c_blk * dtype_bytes
-    else:
-        band_h = (rb_p - 1) * stride + r
-        band_w = (rb_q - 1) * stride + s
-        x_bytes = band_h * band_w * c_blk * dtype_bytes
+    if not whole_plane:
+        blocks, scratch = tiled_conv_buffers(
+            q=q, r=r, s=s, stride=stride, rb_p=rb_p, c_blk=c_blk,
+            k_blk=k_blk, dtype_bytes=dtype_bytes,
+            kind=kind if kind in ("wu", "q8") else "fwd", cols=q)
+        return sum(math.prod(sh) * b for sh, b in blocks + scratch)
+    hp, wp = h + 2 * padding + r, w + 2 * padding       # padded upper bound
+    x_bytes = hp * wp * c_blk * dtype_bytes
     if kind == "wu":
-        do_tile = rb_p * rb_q * k_blk * dtype_bytes
+        do_tile = rb_p * q * k_blk * dtype_bytes
         dw_acc = r * s * c_blk * k_blk * 4           # f32 revisited tile
         return x_bytes + do_tile + dw_acc
     wblk = r * s * c_blk * k_blk * dtype_bytes
     out_bytes = 4 if kind == "q8" else dtype_bytes   # q8 stores f32 (§II-K)
-    out = rb_p * rb_q * k_blk * out_bytes
-    acc = rb_p * rb_q * k_blk * 4
+    out = rb_p * q * k_blk * out_bytes
+    acc = rb_p * q * k_blk * 4
     return x_bytes + wblk + out + acc
 
 
@@ -118,12 +155,13 @@ def conv_blocking_analytic(*, h: int, w: int, c: int, k: int, r: int, s: int,
     keeps the full-C padded plane in VMEM, the streams kernel a C_blk slice
     of it.  The forward path — and, with ``kind="wu"`` and
     ``require_divisor=False``, the tiled update pass — is band-streamed: the
-    working set is the row band, so the budget constrains the *band* — C
-    stays unblocked (single accumulation pass) and RB_Q the full row unless
-    the band itself would not fit, which is exactly the large-image regime
-    the tiling exists for.  ``kind`` selects the per-step residency model of
-    ``conv_working_set`` ("bwd" — the dual forward launch — models as
-    "fwd").
+    working set is a full-width row band, so the budget constrains the
+    *band* — C stays unblocked (single accumulation pass) unless even a
+    one-row band would not fit, and then RB_P alone fits the rest.  When
+    nothing fits, the one-row lane-block candidate is returned with its
+    (over-budget) ``vmem_bytes``.  ``kind`` selects the per-step residency
+    model of ``conv_working_set`` ("bwd" — the dual forward launch — models
+    as "fwd").
     """
     p = (h + 2 * padding - r) // stride + 1
     q = (w + 2 * padding - s) // stride + 1
@@ -134,7 +172,6 @@ def conv_blocking_analytic(*, h: int, w: int, c: int, k: int, r: int, s: int,
     # c_blk is the reported blocking knob; c_model is what sits in VMEM
     # (the legacy wu kernel has no C blocking — its plane is resident at
     # full C)
-    rb_q = q
     if require_divisor:
         c_blk, c_model = aligned_block(c), c
     elif whole:
@@ -142,20 +179,16 @@ def conv_blocking_analytic(*, h: int, w: int, c: int, k: int, r: int, s: int,
     else:
         c_blk = c_model = c
 
-    def ws(rb_p: int, c_m: int, rb_q: int) -> int:
+    def ws(rb_p: int, c_m: int) -> int:
         return conv_working_set(h=h, w=w, c=c, k_blk=k_blk, r=r, s=s, q=q,
                                 rb_p=rb_p, padding=padding,
                                 dtype_bytes=dtype_bytes, stride=stride,
-                                c_blk=c_m, rb_q=rb_q, whole_plane=whole,
-                                kind=ws_kind)
+                                c_blk=c_m, whole_plane=whole, kind=ws_kind)
 
-    if not whole:
+    if not whole and ws(1, c_model) > vmem_budget:
         # prefer a single accumulation pass (c_blk = c); fall back to the
         # lane-aligned block when even a one-row band would blow the budget
-        if ws(1, c_model, rb_q) > vmem_budget:
-            c_blk = c_model = aligned_block(c)
-        while ws(1, c_model, rb_q) > vmem_budget and rb_q > 1:
-            rb_q = math.ceil(rb_q / 2)          # wide image: block the row
+        c_blk = c_model = aligned_block(c)
 
     cands = divisors(p) if require_divisor else list(range(1, p + 1))
     # smallest rb_p with a full-height MXU M-tile, then grow while VMEM
@@ -169,16 +202,16 @@ def conv_blocking_analytic(*, h: int, w: int, c: int, k: int, r: int, s: int,
     grow_to_budget = kind in ("wu", "q8") and not whole
     best = cands[0]
     for rb in cands:
-        if ws(rb, c_model, rb_q) > vmem_budget:
+        if ws(rb, c_model) > vmem_budget:
             break
         best = rb
-        if rb * rb_q >= MXU and not grow_to_budget:
+        if rb * q >= MXU and not grow_to_budget:
             break
     # §II-C: for 1x1 convs pull the C loop in (order "npkc" keeps the output
     # tile resident across C-blocks -> more output register reuse).
     order = "npkc" if (r == 1 and s == 1) else "nkpc"
     return ConvBlocking(rb_p=best, k_blk=k_blk, c_blk=c_blk, order=order,
-                        vmem_bytes=ws(best, c_model, rb_q), rb_q=rb_q)
+                        vmem_bytes=ws(best, c_model))
 
 
 def conv_blocking(*, h: int, w: int, c: int, k: int, r: int, s: int,
@@ -269,8 +302,7 @@ def chain_working_set(layers, *, rows_out: int, dtype_bytes: int = 4,
                               r=L["r"], s=L["s"], q=q,
                               rb_p=min(rows[l + 1], p),
                               padding=L["padding"], dtype_bytes=dtype_bytes,
-                              stride=L["stride"], c_blk=blk.c_blk,
-                              rb_q=blk.rb_q)
+                              stride=L["stride"], c_blk=blk.c_blk)
         peak = max(peak, ws)
     return peak
 

@@ -99,6 +99,23 @@ def tile_cols(q: int, itemsize: int) -> int:
     return -(-q // pack) * pack
 
 
+def vmem_buffer_bytes(shape, itemsize: int) -> int:
+    """One VMEM buffer of ``shape``, its two minor dims padded to the
+    (8 x 32-bit sublane, 128-lane) tile."""
+    *lead, sub, lane = (1, *shape) if len(shape) == 1 else shape
+    sub_tile = 8 * max(4 // itemsize, 1)
+    return (math.prod(lead) * (-(-sub // sub_tile) * sub_tile)
+            * (-(-lane // 128) * 128) * itemsize)
+
+
+def pipelined_vmem_bytes(blocks, scratch=()) -> int:
+    """Scoped VMEM one ``pallas_call`` grid step needs: every pipelined
+    block double-buffered, plus the scratch, each tile-padded.  ``blocks``
+    and ``scratch`` are (shape, itemsize) pairs."""
+    return (2 * sum(vmem_buffer_bytes(sh, b) for sh, b in blocks)
+            + sum(vmem_buffer_bytes(sh, b) for sh, b in scratch))
+
+
 def phase_planes(x, *, padding: int, stride: int, r: int, s: int, p: int,
                  rb_p: int, cols: int):
     """Zero-pad x (N,H,W,C) and split it into stride-phase planes
@@ -147,25 +164,20 @@ def band_tap(x_ref, rr: int, ss: int, *, rows: int, cols: int, stride: int):
     return xs.reshape(rows * cols, xs.shape[-1])
 
 
-def _vmem_bytes(shape, dtype) -> int:
-    """One VMEM buffer of ``shape``, its two minor dims padded to the
-    (8 x 32-bit sublane, 128-lane) tile."""
-    itemsize = jnp.dtype(dtype).itemsize
-    *lead, sub, lane = (1, *shape) if len(shape) == 1 else shape
-    sub_tile = 8 * max(4 // itemsize, 1)
-    return (math.prod(lead) * (-(-sub // sub_tile) * sub_tile)
-            * (-(-lane // 128) * 128) * itemsize)
+# scoped VMEM asked for on top of the blocks, for the unrolled tap chain's
+# temporaries
+VMEM_HEADROOM = 16 << 20
 
 
 def compiler_params(semantics, *, blocks, scratch=()):
     """Mosaic parameters with an explicit scoped-VMEM limit: every pipelined
-    block double-buffered plus the scratch, tile-padded, plus headroom for
-    the unrolled tap chain's temporaries.  ``blocks``/``scratch`` are
-    (shape, dtype) pairs."""
-    need = (2 * sum(_vmem_bytes(sh, dt) for sh, dt in blocks)
-            + sum(_vmem_bytes(sh, dt) for sh, dt in scratch))
+    block double-buffered plus the scratch, tile-padded, plus
+    ``VMEM_HEADROOM``.  ``blocks``/``scratch`` are (shape, dtype) pairs."""
+    need = pipelined_vmem_bytes(
+        [(sh, jnp.dtype(dt).itemsize) for sh, dt in blocks],
+        [(sh, jnp.dtype(dt).itemsize) for sh, dt in scratch])
     return pltpu.CompilerParams(dimension_semantics=tuple(semantics),
-                                vmem_limit_bytes=need + (16 << 20))
+                                vmem_limit_bytes=need + VMEM_HEADROOM)
 
 
 def _unpack_fuse_refs(refs, fuse: FuseSpec):

@@ -210,16 +210,16 @@ def composite_roofline(parts: list[dict], *, extra_hbm_bytes: float = 0.0,
                         util=t.get("util", 1.0), n_steps=t.get("n_steps", 0),
                         step_overhead_s=step_overhead_s)["cost_s"]
         for t in parts)
-    flops = sum(t["flops"] for t in parts)
-    ideal = flops / PEAK_FLOPS
+    # summed per launch, in cost's order: ideal <= cost then holds term by
+    # term and survives the rounding of both sums
+    ideal = sum(t["flops"] / PEAK_FLOPS for t in parts)
     return {
         "cost_s": cost,
-        "flops": flops,
+        "flops": sum(t["flops"] for t in parts),
         "hbm_bytes": extra_hbm_bytes + sum(t["hbm_bytes"] for t in parts),
         "n_steps": sum(t.get("n_steps", 0) for t in parts),
         "launches": len(parts),
-        # ideal <= cost term by term; the sums may round past it by an ulp
-        "efficiency": min(ideal / cost, 1.0) if cost > 0 else 0.0,
+        "efficiency": ideal / cost if cost > 0 else 0.0,
     }
 
 

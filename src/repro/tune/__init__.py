@@ -32,7 +32,7 @@ from repro.tune.measure import (can_measure, conv_cost_us,  # noqa: F401
 from repro.tune.space import (conv_candidates,  # noqa: F401
                               matmul_candidates, out_dim)
 
-_CONV_FIELDS = ("rb_p", "k_blk", "c_blk", "order", "vmem_bytes", "rb_q")
+_CONV_FIELDS = ("rb_p", "k_blk", "c_blk", "order", "vmem_bytes")
 
 
 def _to_conv(entry: dict, *, c: int, k: int) -> ConvBlocking | None:
@@ -40,8 +40,6 @@ def _to_conv(entry: dict, *, c: int, k: int) -> ConvBlocking | None:
     if not all(f in blk for f in _CONV_FIELDS):
         return None
     if k % blk["k_blk"] or c % blk["c_blk"]:    # key drift safety net
-        return None
-    if blk["rb_q"] < 0:
         return None
     if blk["vmem_bytes"] > VMEM_BUDGET:
         # the cache key has no budget coordinate: an entry tuned under the

@@ -23,8 +23,9 @@ import tempfile
 import threading
 import time
 
-CACHE_VERSION = 5     # v5: full-row tiles (RB_Q pinned to Q), feature
-                      #     blocks 128 or the whole dim, phase-plane bands
+CACHE_VERSION = 5     # v5: full-row tiles (no rb_q field), feature blocks
+                      #     128 or the whole dim, vmem_bytes = the kernel's
+                      #     double-buffered, tile-padded phase-plane band
                       # v4: the "q8" int8-forward kind + its 1-byte-input
                       #     working-set model (grow-to-budget rb_p)
                       # v3: tiled-wu space (c_blk/rb_q free, ceil-div rb_p)

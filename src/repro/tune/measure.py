@@ -62,6 +62,16 @@ def _tile_util(extent: int) -> float:
     return extent / (LANE * math.ceil(extent / LANE))
 
 
+def _band_elems(*, rb_p: int, q: int, r: int, s: int, stride: int) -> int:
+    """Input pixels one streamed row band carries per channel, counted in
+    logical pixels: ``rb_p + (r-1)//stride`` rows of the
+    min(stride, r)·min(stride, s) stride-phase planes, each a full row of
+    ``q + (s-1)//stride`` columns (the kernels' sublane padding of the row
+    to Q' is not charged)."""
+    return ((rb_p + (r - 1) // stride) * min(stride, r) * min(stride, s)
+            * (q + (s - 1) // stride))
+
+
 def _refetches(dep_positions: list[int], extents: tuple[int, ...]) -> int:
     """Times a block is (re)fetched over a nested loop: once per iteration of
     every loop at or outside the innermost dependency *that actually varies*."""
@@ -121,19 +131,12 @@ def conv_traffic(shape: dict, blk: ConvBlocking, *, minibatch: int = 1,
                            dtype_bytes=dtype_bytes, whole_plane=whole_plane)
 
     tiled_fwd = kind in ("fwd", "bwd", "q8") and not whole_plane
-    if whole_plane:
-        c_blk, rb_q = c, q
-    elif kind == "streams":
-        c_blk, rb_q = blk.c_blk, q
-    else:
-        c_blk, rb_q = blk.c_blk, (blk.rb_q or q)
+    c_blk = c if whole_plane else blk.c_blk
     rb_p = min(blk.rb_p, p)
-    rb_q = min(rb_q, q)
     p_b = math.ceil(p / rb_p)
-    q_b = math.ceil(q / rb_q) if tiled_fwd else 1
     k_b = max(k // blk.k_blk, 1)
     c_b = max(c // c_blk, 1)
-    extents = (n, k_b, p_b * q_b, c_b)
+    extents = (n, k_b, p_b, c_b)
 
     # the legacy whole-plane fwd has a fixed grid order
     order = "nkpc" if whole_plane else blk.order
@@ -145,13 +148,12 @@ def conv_traffic(shape: dict, blk: ConvBlocking, *, minibatch: int = 1,
 
     # compute: every grid step runs the full (r,s) small-GEMM chain
     flops = 2.0 * n * p * q * c * k * r * s
-    util = (_tile_util(rb_p * rb_q) * _tile_util(blk.k_blk)
+    util = (_tile_util(rb_p * q) * _tile_util(blk.k_blk)
             * _tile_util(c_blk))
 
     if tiled_fwd:
-        band_h = (rb_p - 1) * stride + r
-        band_w = (rb_q - 1) * stride + s
-        x_bytes = band_h * band_w * c_blk * dtype_bytes
+        x_bytes = _band_elems(rb_p=rb_p, q=q, r=r, s=s,
+                              stride=stride) * c_blk * dtype_bytes
         x_f = _refetches([pos["n"], pos["p"], pos["c"]], ordered)
     else:
         x_bytes = hp * wp * c_blk * dtype_bytes
@@ -164,7 +166,7 @@ def conv_traffic(shape: dict, blk: ConvBlocking, *, minibatch: int = 1,
         else:
             x_f = _refetches([pos["n"], pos["c"]], ordered)
     w_bytes = r * s * c_blk * blk.k_blk * dtype_bytes
-    o_bytes = rb_p * rb_q * blk.k_blk * 4   # f32 tile (q8 output stays f32)
+    o_bytes = rb_p * q * blk.k_blk * 4      # f32 tile (q8 output stays f32)
     w_f = _refetches([pos["k"], pos["c"]], ordered)
     o_f = _refetches([pos["n"], pos["k"], pos["p"]], ordered)
     revisit = max(extents[3], 1)
@@ -190,7 +192,7 @@ def _wu_traffic(*, h, w, c, k, r, s, stride, p, q, hp, wp, n, blk,
                 dtype_bytes, whole_plane) -> dict:
     """Update-pass traffic: see ``conv_traffic``.  The GEMM per step is
     dW[r,s] += X^T @ dO with M=C_blk, N=K_blk, K=pixel-block, so occupancy
-    is (c_blk, k_blk, rb_p*rb_q)-tiled."""
+    is (c_blk, k_blk, rb_p*Q)-tiled."""
     flops = 2.0 * n * p * q * c * k * r * s
     k_blk = min(blk.k_blk, k)
     if whole_plane:
@@ -204,18 +206,15 @@ def _wu_traffic(*, h, w, c, k, r, s, stride, p, q, hp, wp, n, blk,
         do_traffic = rb_p * q * k_blk * dtype_bytes * n_steps
     else:
         rb_p = min(blk.rb_p, p)
-        rb_q = min(blk.rb_q or q, q)
         c_blk = blk.c_blk or c
-        band_h = (rb_p - 1) * stride + r
-        band_w = (rb_q - 1) * stride + s
         p_b = math.ceil(p / rb_p)
-        q_b = math.ceil(q / rb_q)
-        n_steps = (k // k_blk) * (c // c_blk) * n * p_b * q_b
-        util = _tile_util(c_blk) * _tile_util(k_blk) * _tile_util(rb_p * rb_q)
-        # band + dO tile are re-streamed on every step ((n, p, q) are the
+        n_steps = (k // k_blk) * (c // c_blk) * n * p_b
+        util = _tile_util(c_blk) * _tile_util(k_blk) * _tile_util(rb_p * q)
+        # band + dO tile are re-streamed on every step ((n, p) are the
         # innermost grid axes; each C-block pass re-reads the dO tiles)
-        x_traffic = band_h * band_w * c_blk * dtype_bytes * n_steps
-        do_traffic = rb_p * rb_q * k_blk * dtype_bytes * n_steps
+        x_traffic = (_band_elems(rb_p=rb_p, q=q, r=r, s=s, stride=stride)
+                     * c_blk * dtype_bytes * n_steps)
+        do_traffic = rb_p * q * k_blk * dtype_bytes * n_steps
     # each (r, s, C_blk, K_blk) f32 tile is written exactly once — the
     # (n, p, q) accumulation revisits never leave VMEM
     dw_traffic = r * s * c * k * 4

@@ -5,7 +5,7 @@ kernels expose:
 
   rb_p   output rows per microkernel (paper RB_P; MXU M-tile = rb_p*Q —
          the tiled kernels always compute full output rows, so RB_Q is
-         pinned to Q and is no coordinate)
+         no coordinate)
   k_blk  output-feature block (paper K_b; MXU N-tile, must divide K)
   c_blk  input-feature block (paper C_b accumulation; must divide C)
   order  grid/dryrun loop order over (N, K_b, P_b, C_b) (paper §II-C)
@@ -113,8 +113,7 @@ def conv_candidates(*, h: int, w: int, c: int, k: int, r: int, s: int,
                         continue
                     seen.add(key)
                     pool.append(ConvBlocking(rb_p=rb, k_blk=kb, c_blk=cb,
-                                             order=order, vmem_bytes=ws,
-                                             rb_q=q))
+                                             order=order, vmem_bytes=ws))
     if len(pool) > MAX_CANDIDATES - 1:
         # spread-sample the (rb_p-major) pool instead of truncating its
         # prefix: a prefix cut would exhaust the budget inside the first

@@ -1,11 +1,20 @@
 """Blocking heuristics (§II-B/C/D on TPU constraints): VMEM budget
-respected, MXU-aligned blocks, divisor mode, loop-order rule."""
+respected, MXU-aligned blocks, divisor mode, loop-order rule, and a VMEM
+model that prices what the kernels ask for."""
+import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core.blocking import (VMEM_BUDGET, conv_blocking,
-                                 conv_blocking_analytic, divisors,
-                                 matmul_blocking)
+                                 conv_blocking_analytic, conv_working_set,
+                                 divisors, matmul_blocking,
+                                 tiled_conv_buffers)
+from repro.kernels.conv2d_direct import (VMEM_HEADROOM, conv2d_direct,
+                                         pipelined_vmem_bytes)
+from repro.kernels.conv2d_q8 import conv2d_q8
+from repro.kernels.conv2d_wu import conv2d_wu
 from repro.core.wu_strategy import choose_wu_strategy, hybrid_copies
 from repro.graph.topology import RESNET50_LAYERS
 
@@ -65,6 +74,67 @@ def test_analytic_vmem_model_matches_kernel_residency():
     assert tiled.vmem_bytes < plane                   # band, not plane
     assert streams.vmem_bytes >= hp * wp * streams.c_blk * 4
     assert wu.vmem_bytes >= plane                     # full-C plane resident
+
+
+VMEM_CASES = [
+    # kind, h, w, c, k, r, stride, pad, rb_p, c_blk
+    ("fwd", 14, 14, 64, 128, 3, 1, 1, 4, None),      # Q off the tile
+    ("fwd", 56, 56, 256, 256, 1, 2, 0, 8, 128),      # 1x1 s2, C blocked
+    ("fwd", 224, 224, 8, 64, 7, 2, 3, 2, None),      # stem: 4 phase planes
+    ("wu", 28, 28, 128, 128, 3, 2, 1, 7, None),
+    ("wu", 7, 7, 512, 256, 3, 1, 1, 7, 128),
+    ("q8", 28, 28, 128, 128, 3, 1, 1, 16, None),     # int8 sublane tile
+    ("q8", 14, 14, 256, 256, 1, 2, 0, 7, 128),
+]
+
+
+def _vmem_request(fn, *args) -> int:
+    """The scoped-VMEM limit the traced kernel asks Mosaic for."""
+    eqns = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+    assert len(eqns) == 1
+    return eqns[0].params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+
+
+@pytest.mark.parametrize("case", VMEM_CASES)
+def test_working_set_is_the_kernels_vmem_request(case):
+    """The VMEM model prices the buffers each tiled kernel declares: the
+    kernel's scoped-VMEM request is exactly those buffers double-buffered
+    and tile-padded plus the fixed headroom, and ``conv_working_set`` is
+    the same buffers in logical pixels (rows of Q), one copy each."""
+    kind, h, w, c, k, r, stride, pad, rb_p, c_blk = case
+    p = (h + 2 * pad - r) // stride + 1
+    k_blk = min(k, 128)
+    x = jnp.zeros((1, h, w, c), jnp.float32)
+    wt = jnp.zeros((r, r, c, k), jnp.float32)
+    if kind == "fwd":
+        fn = lambda x, wt: conv2d_direct(
+            x, wt, stride=stride, padding=pad, rb_p=rb_p, k_blk=k_blk,
+            c_blk=c_blk, whole_plane=False, interpret=True)
+    elif kind == "wu":
+        wt = jnp.zeros((1, p, p, k), jnp.float32)          # dO
+        fn = lambda x, do: conv2d_wu(
+            x, do, stride=stride, padding=pad, filter_rs=(r, r), b_p=rb_p,
+            k_blk=k_blk, c_blk=c_blk, whole_plane=False, interpret=True)
+    else:
+        x, wt = x.astype(jnp.int8), wt.astype(jnp.int8)
+        fn = lambda x, wt: conv2d_q8(
+            x, wt, x_scale=jnp.float32(1), w_scale=jnp.ones((k,)),
+            stride=stride, padding=pad, rb_p=rb_p, k_blk=k_blk, c_blk=c_blk,
+            whole_plane=False, interpret=True)
+    db = 1 if kind == "q8" else 4
+    blocks, scratch = tiled_conv_buffers(
+        q=p, r=r, s=r, stride=stride, rb_p=rb_p, c_blk=c_blk or c,
+        k_blk=k_blk, dtype_bytes=db, kind=kind)
+    assert (_vmem_request(fn, x, wt) - VMEM_HEADROOM
+            == pipelined_vmem_bytes(blocks, scratch))
+    blocks, scratch = tiled_conv_buffers(
+        q=p, r=r, s=r, stride=stride, rb_p=rb_p, c_blk=c_blk or c,
+        k_blk=k_blk, dtype_bytes=db, kind=kind, cols=p)
+    assert conv_working_set(
+        h=h, w=w, c=c, k_blk=k_blk, r=r, s=r, q=p, rb_p=rb_p, padding=pad,
+        dtype_bytes=db, stride=stride, c_blk=c_blk, kind=kind) \
+        == sum(np.prod(sh) * b for sh, b in blocks + scratch)
 
 
 def test_matmul_blocking_budget():

@@ -181,29 +181,29 @@ def test_resnet_stem_tiled_regression(rng):
     """ResNet conv1 (224x224 input, 7x7 stride-2 -> 112x112): the padded
     input plane exceeds a small VMEM budget on the whole-plane path — the
     shape only runs blocked.  Pin bit-exactness of the tiled kernel and
-    H*W-independence of its working set."""
+    H-independence of its working set."""
     sh = STEM_CONV
+    small_budget = 1 << 20            # the CI kernel-tiling smoke budget
     blk = conv_blocking_analytic(
         h=sh["h"], w=sh["w"], c=sh["c"], k=sh["k"], r=sh["r"], s=sh["s"],
-        stride=sh["stride"], padding=sh["padding"])
+        stride=sh["stride"], padding=sh["padding"], vmem_budget=small_budget)
 
     def ws(shape, whole):
         q = out_dim(shape["w"], shape["s"], shape["stride"],
                     shape["padding"])
-        # rb_q pinned: with a fixed (rb_p, rb_q, c_blk) tile the tiled
-        # working set must not see the image size at all
+        # with a fixed (rb_p, c_blk) band of full rows the tiled working
+        # set must not see the image height at all
         return conv_working_set(
             h=shape["h"], w=shape["w"], c=shape["c"], k_blk=blk.k_blk,
             r=shape["r"], s=shape["s"], q=q, rb_p=blk.rb_p,
             padding=shape["padding"], stride=shape["stride"],
-            c_blk=None if whole else blk.c_blk,
-            rb_q=None if whole else 16, whole_plane=whole)
+            c_blk=None if whole else blk.c_blk, whole_plane=whole)
 
-    small_budget = 1 << 20            # the CI kernel-tiling smoke budget
     assert ws(STEM_CONV, whole=True) > small_budget        # legacy: too big
     assert ws(STEM_CONV, whole=False) <= small_budget      # tiled: fits
-    # tiled working set is independent of the image size (same band)
-    assert ws(STEM_CONV, whole=False) == ws(STEM_CONV_HALF, whole=False)
+    # tiled working set is independent of the image height (same band)
+    short = dict(STEM_CONV, h=STEM_CONV_HALF["h"])
+    assert ws(STEM_CONV, whole=False) == ws(short, whole=False)
     assert ws(STEM_CONV_HALF, whole=True) < ws(STEM_CONV, whole=True)
 
     x, wt = _data(rng, sh["n"], sh["h"], sh["w"], sh["c"], sh["k"], sh["r"])
@@ -261,12 +261,14 @@ def test_wu_stem_tiled_regression(rng):
     """The training-pass acceptance bar: the update pass of the 224x224 7x7
     stride-2 stem — un-schedulable for the legacy resident-plane kernel
     under a 1 MiB budget, and P=112 has awkward divisors — runs band-
-    streamed with a working set independent of H*W."""
+    streamed with a working set independent of H."""
     sh = STEM_CONV
     p = out_dim(sh["h"], sh["r"], sh["stride"], sh["padding"])
+    small_budget = 1 << 20            # the CI training-pass smoke budget
     blk = conv_blocking_analytic(
         h=sh["h"], w=sh["w"], c=sh["c"], k=sh["k"], r=sh["r"], s=sh["s"],
-        stride=sh["stride"], padding=sh["padding"], kind="wu")
+        stride=sh["stride"], padding=sh["padding"], kind="wu",
+        vmem_budget=small_budget)
 
     def ws(shape, whole):
         q = out_dim(shape["w"], shape["s"], shape["stride"],
@@ -275,14 +277,14 @@ def test_wu_stem_tiled_regression(rng):
             h=shape["h"], w=shape["w"], c=shape["c"], k_blk=blk.k_blk,
             r=shape["r"], s=shape["s"], q=q, rb_p=blk.rb_p,
             padding=shape["padding"], stride=shape["stride"],
-            c_blk=None if whole else blk.c_blk,
-            rb_q=None if whole else 16, whole_plane=whole, kind="wu")
+            c_blk=None if whole else blk.c_blk, whole_plane=whole,
+            kind="wu")
 
-    small_budget = 1 << 20            # the CI training-pass smoke budget
     assert ws(STEM_CONV, whole=True) > small_budget        # legacy: too big
     assert ws(STEM_CONV, whole=False) <= small_budget      # tiled: fits
-    # tiled working set is independent of the image size (same band)
-    assert ws(STEM_CONV, whole=False) == ws(STEM_CONV_HALF, whole=False)
+    # tiled working set is independent of the image height (same band)
+    short = dict(STEM_CONV, h=STEM_CONV_HALF["h"])
+    assert ws(STEM_CONV, whole=False) == ws(short, whole=False)
     assert ws(STEM_CONV_HALF, whole=True) < ws(STEM_CONV, whole=True)
 
     x, _ = _data(rng, sh["n"], sh["h"], sh["w"], sh["c"], sh["k"], sh["r"])

@@ -120,14 +120,15 @@ def test_conv2d_q8_within_analytic_bound(rng, case, jit):
 
 def test_q8_stem_tiled_under_pressure_budget(rng):
     """The serving acceptance bar: the 224x224 7x7 stride-2 stem is
-    un-schedulable whole-plane under the 1 MiB CI budget, but the int8
-    band fits with room to grow — and the tiled working set is independent
-    of H*W (same band for the 224 and 112 image)."""
+    un-schedulable whole-plane under the 1 MiB CI budget, but the int8 band
+    fits with room to grow — and the tiled working set is independent of H
+    (same band for the 224- and 112-row image)."""
     sh = STEM_CONV
+    small_budget = 1 << 20            # the CI q8-smoke budget
     blk = conv_blocking_analytic(
         h=sh["h"], w=sh["w"], c=sh["c"], k=sh["k"], r=sh["r"], s=sh["s"],
         stride=sh["stride"], padding=sh["padding"], dtype_bytes=1,
-        kind="q8")
+        kind="q8", vmem_budget=small_budget)
 
     def ws(shape, whole):
         q = out_dim(shape["w"], shape["s"], shape["stride"],
@@ -136,19 +137,19 @@ def test_q8_stem_tiled_under_pressure_budget(rng):
             h=shape["h"], w=shape["w"], c=shape["c"], k_blk=blk.k_blk,
             r=shape["r"], s=shape["s"], q=q, rb_p=blk.rb_p,
             padding=shape["padding"], stride=shape["stride"],
-            c_blk=None if whole else blk.c_blk,
-            rb_q=None if whole else 16, whole_plane=whole,
+            c_blk=None if whole else blk.c_blk, whole_plane=whole,
             dtype_bytes=1, kind="q8")
 
-    small_budget = 1 << 20            # the CI q8-smoke budget
     assert ws(STEM_CONV, whole=True) > small_budget        # legacy: too big
     assert ws(STEM_CONV, whole=False) <= small_budget      # tiled: fits
-    assert ws(STEM_CONV, whole=False) == ws(STEM_CONV_HALF, whole=False)
+    short = dict(STEM_CONV, h=STEM_CONV_HALF["h"])           # same width
+    assert ws(STEM_CONV, whole=False) == ws(short, whole=False)
     # the int8 band is 4x smaller than the f32 one, so the same budget
     # admits a taller row block than the f32 blocking gets
     f32_blk = conv_blocking_analytic(
         h=sh["h"], w=sh["w"], c=sh["c"], k=sh["k"], r=sh["r"], s=sh["s"],
-        stride=sh["stride"], padding=sh["padding"], dtype_bytes=4)
+        stride=sh["stride"], padding=sh["padding"], dtype_bytes=4,
+        vmem_budget=small_budget)
     assert blk.rb_p >= f32_blk.rb_p
 
     x = jnp.asarray(rng.standard_normal(

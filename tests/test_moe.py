@@ -44,10 +44,7 @@ def test_moe_matches_bruteforce_at_high_capacity(rng):
                                expect, rtol=2e-3, atol=2e-3)
 
 
-# Derandomized: the lb_loss floor holds for typical routers, not for every
-# draw (about 1 seed in 250 lands under 0.99), so a fresh random search would
-# fail now and then, and the saved example would then fail every rerun.
-@settings(max_examples=10, deadline=None, derandomize=True)
+@settings(max_examples=10, deadline=None)
 @given(e=st.sampled_from([2, 4]), k=st.integers(1, 2),
        cf=st.sampled_from([0.5, 1.0, 4.0]), seed=st.integers(0, 2**31 - 1))
 def test_moe_dispatch_invariants(e, k, cf, seed):
@@ -59,7 +56,18 @@ def test_moe_dispatch_invariants(e, k, cf, seed):
     out, aux = moe.apply(p, cfg, x)
     assert out.shape == x.shape
     assert bool(jnp.isfinite(out).all())
-    assert float(aux["lb_loss"]) >= 0.99  # >= 1 at optimum for uniform
+    # Switch load-balancing loss E * sum_i f_i * P_i (f: top-1 routing
+    # fractions, P: mean router probabilities), recomputed with the same
+    # ops.  A token's top-1 probability is at least 1/E, so P_i >= f_i / E
+    # and the loss is at least sum_i f_i^2 (>= 1/E); P_i <= 1 caps it at E.
+    # It can fall below 1: a skewed router whose minority tokens are
+    # confident.
+    probs = jax.nn.softmax((x @ p["router"]).astype(jnp.float32), axis=-1)
+    f = jax.nn.one_hot(jnp.argmax(probs, axis=-1), e).mean(axis=(0, 1))
+    want = e * float(jnp.sum(probs.mean(axis=(0, 1)) * f))
+    lb = float(aux["lb_loss"])
+    np.testing.assert_allclose(lb, want, rtol=1e-5)
+    assert float(jnp.sum(f * f)) * (1 - 1e-5) <= lb <= e * (1 + 1e-5)
     assert np.isfinite(float(aux["z_loss"]))
 
 

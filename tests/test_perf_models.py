@@ -32,7 +32,7 @@ def _shape(h, c, k, rs_pad, stride):
 def _blk(shape):
     return ConvBlocking(rb_p=2, k_blk=min(shape["k"], 64),
                         c_blk=min(shape["c"], 32), order="nkpc",
-                        vmem_bytes=0, rb_q=4)
+                        vmem_bytes=0)
 
 
 @settings(max_examples=25)
@@ -62,22 +62,24 @@ def test_traffic_nondecreasing_in_plane_size(draw, kind, whole_plane):
 
 
 @settings(max_examples=25)
-@given(_shapes, st.integers(1, 4), st.integers(1, 8),
-       st.sampled_from(["fwd", "wu"]))
-def test_band_working_set_independent_of_plane(draw, rb_p, rb_q, kind):
-    """The §II-B claim the tiling rests on: for a fixed (rb_p, rb_q, c_blk)
-    band, per-step VMEM is the same at 7x7 and at 224x224 — only the
-    whole-plane legacy schedule scales with H*W."""
+@given(_shapes, st.integers(1, 4), st.sampled_from(["fwd", "wu", "q8"]))
+def test_band_working_set_independent_of_plane(draw, rb_p, kind):
+    """The §II-B claim the tiling rests on: for a fixed (rb_p, c_blk)
+    band of full-width rows, per-step VMEM is the same for a 7-row and a
+    224-row image of the same width — only the whole-plane legacy schedule
+    scales with H*W."""
     shape = _shape(*draw)
     kw = dict(c=shape["c"], k_blk=64, r=shape["r"], s=shape["s"],
-              rb_p=rb_p, rb_q=rb_q, c_blk=32, padding=shape["padding"],
-              stride=shape["stride"], kind=kind)
+              rb_p=rb_p, c_blk=32, padding=shape["padding"],
+              stride=shape["stride"], kind=kind,
+              dtype_bytes=1 if kind == "q8" else 4)
     q_of = lambda w: (w + 2 * shape["padding"] - shape["s"]) \
         // shape["stride"] + 1
     ws = conv_working_set(h=shape["h"], w=shape["w"], q=q_of(shape["w"]),
                           **kw)
-    ws_big = conv_working_set(h=224, w=224, q=q_of(224), **kw)
-    assert ws == ws_big
+    ws_tall = conv_working_set(h=224, w=shape["w"], q=q_of(shape["w"]),
+                               **kw)
+    assert ws == ws_tall
     # while the resident-plane model must grow with the image
     wp = conv_working_set(h=shape["h"], w=shape["w"], q=q_of(shape["w"]),
                           whole_plane=True, **kw)

@@ -76,8 +76,7 @@ def test_cached_entry_rejected_under_forced_budget(tmp_path, monkeypatch):
               kind="fwd", backend="xla")
     key = tune.conv_key(dtype_bytes=4, **kw)
     c.store(key, dict(rb_p=4, k_blk=128, c_blk=256, order="nkpc",
-                      vmem_bytes=2 << 20, rb_q=14), source="model",
-            score_us=1.0)
+                      vmem_bytes=2 << 20), source="model", score_us=1.0)
     assert tune.lookup_conv(**kw, cache=c) is not None
     monkeypatch.setattr(tune, "VMEM_BUDGET", 1 << 20)
     assert tune.lookup_conv(**kw, cache=c) is None
@@ -134,22 +133,23 @@ def test_candidates_respect_constraints():
 def test_wu_candidates_free_cblk_rbq_and_tails():
     """The tiled update pass freed the wu space: rb_p is ceil-div (dO is
     zero-padded, so non-divisors of P are legal candidates) and c_blk is a
-    search coordinate, while every tile is a full row (rb_q = Q: the kernel
-    streams full-width bands) — all within the VMEM budget under the
-    band-based wu residency model."""
+    search coordinate, while every tile is a full row (RB_Q is no
+    coordinate: the kernel streams full-width bands) — all within the VMEM
+    budget under the band-based wu residency model, each priced at what
+    the kernel asks for."""
     p = 14
     cands = tune.conv_candidates(h=14, w=14, c=256, k=256, r=3, s=3,
                                  stride=1, padding=1, kind="wu")
     assert any(p % b.rb_p for b in cands)               # non-divisor rb_p
     assert len({b.c_blk for b in cands}) > 1            # C_b freed
-    assert {b.rb_q or p for b in cands} == {p}          # full rows only
+    assert all(not hasattr(b, "rb_q") for b in cands)   # full rows only
     from repro.core.blocking import conv_working_set
     for b in cands:
         assert 256 % b.c_blk == 0 and 256 % b.k_blk == 0
         ws = conv_working_set(h=14, w=14, c=256, k_blk=b.k_blk, r=3, s=3,
                               q=p, rb_p=b.rb_p, padding=1, c_blk=b.c_blk,
-                              rb_q=b.rb_q, kind="wu")
-        assert ws <= VMEM_BUDGET
+                              kind="wu")
+        assert b.vmem_bytes == ws <= VMEM_BUDGET
 
 
 def test_bwd_kind_candidates_and_key_namespace():
