@@ -40,11 +40,14 @@ CLASSES = 1000
 # Tolerances.  Both sides run f32 at highest matmul precision, so they
 # differ only by summation order through 53 convolutions.  The parameter
 # update du = params after the step - params before is ill-conditioned at
-# random init: on the CPU, in f32 XLA at 112x112 and batch 16, a 1-ulp random
-# perturbation of the params moves the whole update by 1.5e-3 and the worst
-# leaf (a BN scale) by 2.4e-2 (ReLU sign flips and batch-norm cancellation).
-# The limits sit a few times above that floor; a wrong tap, halo or phase
-# moves the leaves it touches by O(1).
+# random init (ReLU sign flips, batch-norm cancellation), so each train
+# limit sits between two readings of the step against XLA-highest: the f32
+# floor (params moved by one ulp) and a control that runs every conv but
+# the stem as one bf16 pass, as XLA's default f32 conv does on a TPU.  On
+# the CPU at 112x112, batch 16 (floor / control): loss 1.1e-6 / 2.8e-3,
+# whole update 1.6e-3 / 9.9e-2, worst leaf 2.4e-2 / 1.5; PERF.md has the
+# chip's readings.  A wrong tap, halo or phase moves the leaves it touches
+# by O(1).
 LOGIT_RTOL = 1e-3           # max|got - want| / max|want| over a batch
 LOSS_RTOL = 1e-4            # |loss - loss_ref| / |loss_ref|
 UPDATE_RTOL = 1e-2          # ||du - du_ref|| / ||du_ref|| over all leaves
