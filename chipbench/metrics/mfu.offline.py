@@ -1,0 +1,14 @@
+"""Model FLOPs utilization of serving, in %: forward FLOPs per image
+times images/s (host clock, over the run's measured window, which is
+never traced) over the chip's bf16 peak."""
+from chipbench import counts, device
+
+
+def read(ctx):
+    cfg = ctx["config"]
+    ref = ctx["ref"]
+    layers = ref.conv_layers(cfg, (cfg["image"], cfg["image"]))
+    flops = counts.forward_flops(layers, ref.classifier(cfg))
+    peak = device.peaks(ctx["device_kind"])["flops"]
+    return 100.0 * flops * ctx["summary"]["serve_images_per_s"] \
+        / (ctx["chips"] * peak)
