@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import backend as be
+from repro import obs
 from repro.core import duality
 from repro.core.blocking import conv_blocking
 from repro.kernels import ref
@@ -44,8 +45,20 @@ def conv2d_fwd(x, w, *, stride=1, padding=1, bias=None, scale=None,
     `autotune` (None -> ``repro.backend`` knob) selects how the blocking is
     chosen: "off" analytic, "cache" tuned-if-cached, "tune" search+persist.
     `kind` is the tuner-cache namespace ("fwd", or "bwd" when this forward
-    launch is a backward-data dual conv — same kernel, separately tuned key).
+    launch is a backward-data dual conv — same kernel, separately tuned key)
+    and names the pass: the kernel and its XLA glue run under the named
+    scope ``obs.PASS_OF_KIND[kind]``.
     """
+    with jax.named_scope(obs.PASS_OF_KIND[kind]):
+        return _conv2d_fwd(x, w, stride=stride, padding=padding, bias=bias,
+                           scale=scale, shift=shift, residual=residual,
+                           relu=relu, impl=impl, autotune=autotune,
+                           kind=kind)
+
+
+def _conv2d_fwd(x, w, *, stride, padding, bias=None, scale=None, shift=None,
+                residual=None, relu=False, impl=None, autotune=None,
+                kind="fwd"):
     impl = be.resolve(impl)
     n, h, wdt, c = x.shape
     r, s, _, k = w.shape
@@ -61,7 +74,8 @@ def conv2d_fwd(x, w, *, stride=1, padding=1, bias=None, scale=None,
                          scale=scale, shift=shift, residual=residual,
                          relu=relu, rb_p=blk.rb_p, k_blk=blk.k_blk,
                          c_blk=blk.c_blk, order=blk.order,
-                         interpret=(impl == "interpret"))
+                         interpret=(impl == "interpret"),
+                         name=obs.PASS_OF_KIND[kind])
 
 
 def conv2d_chain_fwd(x, layers, *, rb, impl=None, autotune=None):
@@ -72,8 +86,9 @@ def conv2d_chain_fwd(x, layers, *, rb, impl=None, autotune=None):
     with each layer's blocking pinned to its full shape — which makes the
     result bit-identical to the unfused layer-by-layer execution."""
     from repro.kernels.conv2d_chain import conv2d_chain
-    return conv2d_chain(x, layers, rb=rb, impl=be.resolve(impl),
-                        autotune=autotune)
+    with jax.named_scope(obs.CONV_CHAIN):
+        return conv2d_chain(x, layers, rb=rb, impl=be.resolve(impl),
+                            autotune=autotune)
 
 
 def conv2d_q8_fwd(x, w_q, *, x_scale, w_scale, stride=1, padding=1,
@@ -88,6 +103,15 @@ def conv2d_q8_fwd(x, w_q, *, x_scale, w_scale, stride=1, padding=1,
     ``acc*(deq*bn) + ...``, algebraically identical to the kernel path, so
     the fallback differs only by f32 rounding, not by quantization scheme.
     """
+    with jax.named_scope(obs.CONV_Q8):
+        return _conv2d_q8_fwd(x, w_q, x_scale=x_scale, w_scale=w_scale,
+                              stride=stride, padding=padding, bias=bias,
+                              scale=scale, shift=shift, residual=residual,
+                              relu=relu, impl=impl, autotune=autotune)
+
+
+def _conv2d_q8_fwd(x, w_q, *, x_scale, w_scale, stride, padding, bias, scale,
+                   shift, residual, relu, impl, autotune):
     from repro.core.quantize import quantize_act
     impl = be.resolve(impl)
     n, h, wdt, c = x.shape
@@ -126,8 +150,18 @@ def conv2d_bwd_data_via_fwd(do, w, *, stride, padding, input_hw, impl=None,
     ``REPRO_BWD_DUALITY`` knob: "phase" (default) launches stride² forward
     sub-convs over the *undilated* dO — no dilated intermediate is ever
     allocated; "dilate" is the legacy materialized plan kept for A/B.
-    Every forward launch tunes/looks up its blocking under kind "bwd".
+    Every forward launch tunes/looks up its blocking under kind "bwd", and
+    the whole pass (weight transform, launches, interleave) runs under the
+    named scope ``conv_bwd_data``.
     """
+    with jax.named_scope(obs.CONV_BWD_DATA):
+        return _conv2d_bwd_data_via_fwd(do, w, stride=stride, padding=padding,
+                                        input_hw=input_hw, impl=impl,
+                                        autotune=autotune, mode=mode)
+
+
+def _conv2d_bwd_data_via_fwd(do, w, *, stride, padding, input_hw, impl,
+                             autotune, mode):
     r, s = w.shape[0], w.shape[1]
     scenario, _ = duality.bwd_data_plan(r=r, s=s, stride=stride,
                                         padding=padding, input_hw=input_hw,
@@ -135,13 +169,13 @@ def conv2d_bwd_data_via_fwd(do, w, *, stride, padding, input_hw, impl=None,
     if scenario == "phase":
         return duality.phase_bwd_data(
             do, w, stride=stride, padding=padding, input_hw=input_hw,
-            conv_fn=lambda a, b, st, pd: conv2d_fwd(
+            conv_fn=lambda a, b, st, pd: _conv2d_fwd(
                 a, b, stride=st, padding=pd, impl=impl, autotune=autotune,
                 kind="bwd"))
     do2, wt, kw, post = duality.prepare_bwd_data(
         do, w, stride=stride, padding=padding, input_hw=input_hw, mode=mode)
-    y = conv2d_fwd(do2, wt, stride=kw["stride"], padding=kw["padding"],
-                   impl=impl, autotune=autotune, kind="bwd")
+    y = _conv2d_fwd(do2, wt, stride=kw["stride"], padding=kw["padding"],
+                    impl=impl, autotune=autotune, kind="bwd")
     return post(y)
 
 
@@ -152,7 +186,16 @@ def conv2d_bwd_weights(x, do, *, stride, padding, filter_rs, impl=None,
     The default tiled kernel streams row bands and blocks C/Q with ceil-div
     tails (no divisibility constraints); ``whole_plane`` (default: the
     ``repro.backend`` conv-tiling knob) selects the legacy resident-plane
-    kernel, which still needs ``rb_p | P`` (``require_divisor``)."""
+    kernel, which still needs ``rb_p | P`` (``require_divisor``).  Runs
+    under the named scope ``conv_wu``."""
+    with jax.named_scope(obs.CONV_WU):
+        return _conv2d_bwd_weights(x, do, stride=stride, padding=padding,
+                                   filter_rs=filter_rs, impl=impl,
+                                   autotune=autotune, whole_plane=whole_plane)
+
+
+def _conv2d_bwd_weights(x, do, *, stride, padding, filter_rs, impl, autotune,
+                        whole_plane):
     impl = be.resolve(impl)
     n, h, wdt, c = x.shape
     _, p, q, k = do.shape

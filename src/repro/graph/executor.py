@@ -171,126 +171,132 @@ class GxM:
         chain_plans: dict = {}
 
         for t in self.etg.tasks:
-            a = t.attrs
             if t.op == "input":
                 continue
-            elif t.op == "conv" and t.name in chain_of:
-                ch, pos = chain_of[t.name]
-                if pos == 0:
-                    # decide once per chain, at its entry (the input tensor's
-                    # spatial shape is known here): fuse iff the combined
-                    # band fits VMEM and fusion is profitable
-                    chain_plans[ch.names] = self._plan_chain(
-                        ch, params, get(t.inputs[0]))
-                plan = chain_plans[ch.names]
-                if plan is None:
-                    pass                    # fallback: run layer-by-layer
-                elif pos < len(ch.names) - 1:
-                    continue                # band stays live in the replay
-                else:
-                    out = conv2d_chain_fwd(
-                        get(self._task(ch.names[0]).inputs[0]),
-                        [self._chain_layer(n2, params, get, folded)
-                         for n2 in ch.names],
-                        rb=plan["rb"], impl=self.impl)
-                    tensors[t.name] = out
-                    if "output_name" in a:
-                        tensors[a["output_name"]] = out
-                    continue
-            if t.op == "conv":
-                inp = get(t.inputs[0])
-                if tap is not None:
-                    tap(t.name, inp)
-                p = params[t.name]
-                kw = dict(stride=a["stride"], padding=a["padding"])
-                scale = shift = bias = residual = None
-                relu = False
-                for kind, attrs in t.fused:
-                    if kind == "bn":
-                        scale, shift = p["scale"], p["shift"]
-                    elif kind == "bias":
-                        bias = p["bias"]
-                    elif kind == "relu":
-                        relu = True
-                    elif kind == "add":
-                        residual = get(attrs["residual"])
-                if train:
-                    if "w_q" in p:
-                        raise ValueError(
-                            f"conv {t.name} holds quantized weights (w_q); "
-                            f"the q8 path is inference-only — train with "
-                            f"the f32 params tree")
-                    # training path: paper bwd pipeline via custom VJP;
-                    # normalization handled outside the kernel (batch stats)
-                    y = conv2d_train(inp, p["w"], a["stride"], a["padding"],
-                                     self.impl)
-                    if scale is not None:
-                        mu = y.mean(axis=(0, 1, 2))
-                        var = y.var(axis=(0, 1, 2))
-                        stats[t.name] = (mu, var)
-                        y = (y - mu) * jax.lax.rsqrt(var + 1e-5)
-                        y = y * scale + shift
-                    if bias is not None:
-                        y = y + bias
-                    if residual is not None:
-                        y = y + residual
-                    if relu:
-                        y = jnp.maximum(y, 0)
-                else:
-                    # inference: everything fused into the kernel epilogue,
-                    # BN folded from running stats
-                    if scale is not None:
-                        scale, shift = folded(p)
-                    if a.get("kernel_kind") == "q8" and "w_q" in p:
-                        # §II-K quantized path: int8 kernel, f32 epilogue.
-                        # A q8-marked task with f32 params (no w_q) falls
-                        # through to the f32 kernel — the calibration pass.
-                        y = conv2d_q8_fwd(inp, p["w_q"],
-                                          x_scale=p["x_scale"],
-                                          w_scale=p["w_scale"], bias=bias,
-                                          scale=scale, shift=shift,
-                                          residual=residual, relu=relu,
-                                          impl=self.impl, **kw)
+            # every op of the task, and of its transpose, carries its name
+            with jax.named_scope(t.name):
+                a = t.attrs
+                if t.op == "conv" and t.name in chain_of:
+                    ch, pos = chain_of[t.name]
+                    if pos == 0:
+                        # decide once per chain, at its entry (the input
+                        # tensor's spatial shape is known here): fuse iff the
+                        # combined band fits VMEM and fusion is profitable
+                        chain_plans[ch.names] = self._plan_chain(
+                            ch, params, get(t.inputs[0]))
+                    plan = chain_plans[ch.names]
+                    if plan is None:
+                        pass                    # fallback: run layer-by-layer
+                    elif pos < len(ch.names) - 1:
+                        continue                # band stays live in the replay
                     else:
-                        y = conv2d_fwd(inp, p["w"], bias=bias, scale=scale,
-                                       shift=shift, residual=residual,
-                                       relu=relu, impl=self.impl, **kw)
-                out = y
-            elif t.op == "bn":
-                y = get(t.inputs[0])
-                p = params[t.name]
-                if train:
-                    mu = y.mean(axis=(0, 1, 2))
-                    var = y.var(axis=(0, 1, 2))
-                    stats[t.name] = (mu, var)
+                        out = conv2d_chain_fwd(
+                            get(self._task(ch.names[0]).inputs[0]),
+                            [self._chain_layer(n2, params, get, folded)
+                             for n2 in ch.names],
+                            rb=plan["rb"], impl=self.impl)
+                        tensors[t.name] = out
+                        if "output_name" in a:
+                            tensors[a["output_name"]] = out
+                        continue
+                if t.op == "conv":
+                    inp = get(t.inputs[0])
+                    if tap is not None:
+                        tap(t.name, inp)
+                    p = params[t.name]
+                    kw = dict(stride=a["stride"], padding=a["padding"])
+                    scale = shift = bias = residual = None
+                    relu = False
+                    for kind, attrs in t.fused:
+                        if kind == "bn":
+                            scale, shift = p["scale"], p["shift"]
+                        elif kind == "bias":
+                            bias = p["bias"]
+                        elif kind == "relu":
+                            relu = True
+                        elif kind == "add":
+                            residual = get(attrs["residual"])
+                    if train:
+                        if "w_q" in p:
+                            raise ValueError(
+                                f"conv {t.name} holds quantized weights "
+                                f"(w_q); the q8 path is inference-only — "
+                                f"train with the f32 params tree")
+                        # training path: paper bwd pipeline via custom VJP;
+                        # normalization handled outside the kernel (batch
+                        # stats)
+                        y = conv2d_train(inp, p["w"], a["stride"],
+                                         a["padding"], self.impl)
+                        if scale is not None:
+                            with jax.named_scope("bn"):
+                                mu = y.mean(axis=(0, 1, 2))
+                                var = y.var(axis=(0, 1, 2))
+                                stats[t.name] = (mu, var)
+                                y = (y - mu) * jax.lax.rsqrt(var + 1e-5)
+                                y = y * scale + shift
+                        if bias is not None:
+                            y = y + bias
+                        if residual is not None:
+                            y = y + residual
+                        if relu:
+                            y = jnp.maximum(y, 0)
+                    else:
+                        # inference: everything fused into the kernel epilogue,
+                        # BN folded from running stats
+                        if scale is not None:
+                            scale, shift = folded(p)
+                        if a.get("kernel_kind") == "q8" and "w_q" in p:
+                            # §II-K quantized path: int8 kernel, f32 epilogue.
+                            # A q8-marked task with f32 params (no w_q) falls
+                            # through to the f32 kernel — the calibration
+                            # pass.
+                            y = conv2d_q8_fwd(inp, p["w_q"],
+                                              x_scale=p["x_scale"],
+                                              w_scale=p["w_scale"], bias=bias,
+                                              scale=scale, shift=shift,
+                                              residual=residual, relu=relu,
+                                              impl=self.impl, **kw)
+                        else:
+                            y = conv2d_fwd(inp, p["w"], bias=bias, scale=scale,
+                                           shift=shift, residual=residual,
+                                           relu=relu, impl=self.impl, **kw)
+                    out = y
+                elif t.op == "bn":
+                    y = get(t.inputs[0])
+                    p = params[t.name]
+                    with jax.named_scope("bn"):
+                        if train:
+                            mu = y.mean(axis=(0, 1, 2))
+                            var = y.var(axis=(0, 1, 2))
+                            stats[t.name] = (mu, var)
+                        else:
+                            mu, var = p["mean"], p["var"]
+                        out = (y - mu) * jax.lax.rsqrt(var + 1e-5) \
+                            * p["scale"] + p["shift"]
+                elif t.op == "relu":
+                    out = jnp.maximum(get(t.inputs[0]), 0)
+                elif t.op == "add":
+                    out = get(t.inputs[0]) + get(t.inputs[1])
+                elif t.op == "split":
+                    out = get(t.inputs[0])
+                elif t.op == "concat":
+                    out = jnp.concatenate([get(i) for i in t.inputs], axis=-1)
+                elif t.op == "maxpool":
+                    out = _maxpool(get(t.inputs[0]), a["window"], a["stride"],
+                                   a["padding"])
+                elif t.op == "avgpool":
+                    out = get(t.inputs[0]).mean(axis=(1, 2))
+                elif t.op == "fc":
+                    p = params[t.name]
+                    # f32 at full precision on every backend, like the conv
+                    # kernels (a TPU's default f32 matmul takes bf16 passes)
+                    out = jnp.dot(get(t.inputs[0]), p["w"],
+                                  precision=jax.lax.Precision.HIGHEST) + p["b"]
                 else:
-                    mu, var = p["mean"], p["var"]
-                out = (y - mu) * jax.lax.rsqrt(var + 1e-5) * p["scale"] \
-                    + p["shift"]
-            elif t.op == "relu":
-                out = jnp.maximum(get(t.inputs[0]), 0)
-            elif t.op == "add":
-                out = get(t.inputs[0]) + get(t.inputs[1])
-            elif t.op == "split":
-                out = get(t.inputs[0])
-            elif t.op == "concat":
-                out = jnp.concatenate([get(i) for i in t.inputs], axis=-1)
-            elif t.op == "maxpool":
-                out = _maxpool(get(t.inputs[0]), a["window"], a["stride"],
-                               a["padding"])
-            elif t.op == "avgpool":
-                out = get(t.inputs[0]).mean(axis=(1, 2))
-            elif t.op == "fc":
-                p = params[t.name]
-                # f32 at full precision on every backend, like the conv
-                # kernels (a TPU's default f32 matmul takes bf16 passes)
-                out = jnp.dot(get(t.inputs[0]), p["w"],
-                              precision=jax.lax.Precision.HIGHEST) + p["b"]
-            else:
-                raise ValueError(f"unknown op {t.op}")
-            tensors[t.name] = out
-            if "output_name" in a:
-                tensors[a["output_name"]] = out
+                    raise ValueError(f"unknown op {t.op}")
+                tensors[t.name] = out
+                if "output_name" in a:
+                    tensors[a["output_name"]] = out
         result = tensors[self.etg.tasks[-1].name]
         if collect_stats:
             return result, stats

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import backend as be
+from repro import obs
 from repro import tune
 from repro.core.blocking import VMEM_BUDGET, conv_blocking
 from repro.core.conv import lane_ok
@@ -313,18 +314,26 @@ class CnnInferenceEngine:
     # -- the request path ----------------------------------------------------
     def infer(self, images):
         """Logits for ``images`` (n, H, W, 3); pads n up to the minimal
-        bucket, runs that bucket's warmed executable, slices padding away."""
+        bucket, runs that bucket's warmed executable, slices padding away.
+        Asynchronous: returns once the work is dispatched.  Its phases
+        ``engine.pad``, ``engine.put`` (handing the batch to the device;
+        the copy finishes asynchronously) and ``engine.run`` (dispatch)
+        are spans and counters (``repro.obs``)."""
         x = np.asarray(images, dtype=self.dtype)
         n = x.shape[0]
         if n > max(self.buckets):
             raise ValueError(f"batch {n} exceeds largest bucket "
                              f"{max(self.buckets)}; chunk it first")
         bucket = pick_bucket(n, self.buckets)
-        if n < bucket:
-            x = np.concatenate(
-                [x, np.zeros((bucket - n, *x.shape[1:]), x.dtype)])
+        with obs.phase("engine.pad", n=n, bucket=bucket):
+            if n < bucket:
+                x = np.concatenate(
+                    [x, np.zeros((bucket - n, *x.shape[1:]), x.dtype)])
+        with obs.phase("engine.put", n=n, bucket=bucket):
+            xd = jnp.asarray(x)
         fn = self._compiled.get(bucket)
-        if fn is not None:
-            return fn(self._run_params, jnp.asarray(x))[:n]
-        with self._autotune_scope():      # unwarmed bucket: trace here
-            return self._fn(self._run_params, jnp.asarray(x))[:n]
+        with obs.phase("engine.run", n=n, bucket=bucket):
+            if fn is not None:
+                return fn(self._run_params, xd)[:n]
+            with self._autotune_scope():  # unwarmed bucket: trace here
+                return self._fn(self._run_params, xd)[:n]

@@ -100,5 +100,6 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="attention",
     )(qr, kr, vr)
     return out.reshape(b, hq, l, dh)

@@ -51,4 +51,5 @@ def conv1d_causal(x, w, *, bias=None, act: str = "silu", d_blk: int = 128,
         out_specs=pl.BlockSpec((1, l, d_blk), lambda bi, di: (bi, 0, di)),
         out_shape=jax.ShapeDtypeStruct((b, l, d), x.dtype),
         interpret=interpret,
+        name="conv1d_causal",
     )(xp, w, bias.reshape(1, d))

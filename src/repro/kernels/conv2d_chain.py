@@ -44,7 +44,8 @@ def _band_conv(xb, L, blk, impl, residual):
         return ref.conv2d_fused(xb, w, **kw)
     return conv2d_direct(xb, w, rb_p=blk.rb_p, k_blk=blk.k_blk,
                          c_blk=blk.c_blk, order=blk.order,
-                         interpret=(impl == "interpret"), **kw)
+                         interpret=(impl == "interpret"), name="conv_chain",
+                         **kw)
 
 
 def conv2d_chain(x, layers, *, rb: int, impl: str, autotune=None):

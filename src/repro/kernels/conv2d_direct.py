@@ -288,7 +288,8 @@ def conv2d_direct(x, w, *, stride: int = 1, padding: int = 0,
                   relu: bool = False, rb_p: int = 8, k_blk: int | None = None,
                   c_blk: int | None = None, order: str = "nkpc",
                   whole_plane: bool | None = None,
-                  accum_dtype=jnp.float32, interpret: bool = False):
+                  accum_dtype=jnp.float32, interpret: bool = False,
+                  name: str = "conv_fwd"):
     """Direct conv fwd.  x: (N,H,W,C), w: (R,S,C,K) -> (N,P,Q,K).
 
     `rb_p` is the paper's RB_P register block (output rows per microkernel;
@@ -300,7 +301,8 @@ def conv2d_direct(x, w, *, stride: int = 1, padding: int = 0,
     must be the whole dim or a multiple of 128 (``core.blocking`` only
     emits such blocks).  `order` is the §II-C loop order of the grid.
     `whole_plane` selects the legacy untiled kernel (default: the
-    ``repro.backend`` conv-tiling knob).
+    ``repro.backend`` conv-tiling knob).  `name` names the kernel (the
+    pass it serves; the legacy kernel gets ``_whole`` after it).
     """
     n, h, wdt, c = x.shape
     r, s, _, k = w.shape
@@ -334,7 +336,7 @@ def conv2d_direct(x, w, *, stride: int = 1, padding: int = 0,
             scale=scale, shift=shift, residual=residual, rb_p=rb_p,
             k_blk=k_blk, p=p, q=q, r=r, s=s, n=n, k=k, c=c,
             accum_dtype=accum_dtype, out_dtype=out_dtype,
-            interpret=interpret)
+            interpret=interpret, name=name + "_whole")
 
     cols = tile_cols(q, x.dtype.itemsize)
     xp = phase_planes(x, padding=padding, stride=stride, r=r, s=s, p=p,
@@ -377,13 +379,14 @@ def conv2d_direct(x, w, *, stride: int = 1, padding: int = 0,
         compiler_params=compiler_params(semantics, blocks=blocks,
                                         scratch=[(acc, accum_dtype)]),
         interpret=interpret,
+        name=name,
     )(*args)
     return out[:, :, :q] if cols != q else out
 
 
 def _conv2d_whole_plane(x, w, *, fuse, stride, padding, bias, scale, shift,
                         residual, rb_p, k_blk, p, q, r, s, n, k, c,
-                        accum_dtype, out_dtype, interpret):
+                        accum_dtype, out_dtype, interpret, name):
     """The pre-refactor kernel: whole padded plane per image in VMEM, C and Q
     unblocked, grid (N, K_b, P_b).  Working set scales with H*W*C."""
     xp = pad_input(x, padding=padding, stride=stride, rb_p=rb_p, r=r, p=p)
@@ -420,4 +423,5 @@ def _conv2d_whole_plane(x, w, *, fuse, stride, padding, bias, scale, shift,
                                lambda ni, ki, pi: (ni, pi, 0, ki)),
         out_shape=jax.ShapeDtypeStruct((n, p, q, k), out_dtype),
         interpret=interpret,
+        name=name,
     )(*args)

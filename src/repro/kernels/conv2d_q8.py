@@ -209,6 +209,7 @@ def conv2d_q8(x_q, w_q, *, x_scale, w_scale, stride: int = 1,
         compiler_params=compiler_params(semantics, blocks=blocks,
                                         scratch=[(acc, jnp.int32)]),
         interpret=interpret,
+        name="conv_q8",
     )(*args)
     return out[:, :, :q] if cols != q else out
 
@@ -251,6 +252,7 @@ def _conv2d_q8_whole_plane(x_q, w_q, deq, *, fuse, stride, padding, bias,
                                lambda ni, ki, pi: (ni, pi, 0, ki)),
         out_shape=jax.ShapeDtypeStruct((n, p, q, k), out_dtype),
         interpret=interpret,
+        name="conv_q8_whole",
     )(*args)
 
 

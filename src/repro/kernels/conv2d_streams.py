@@ -106,6 +106,7 @@ def conv2d_streams(x, w, *, schedule: ConvSchedule, stride: int = 1,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, p, q, k), accum_dtype),
         interpret=interpret,
+        name="conv_streams",
     )(jnp.asarray(schedule.flags), jnp.asarray(schedule.n_ids),
       jnp.asarray(schedule.kb_ids), jnp.asarray(schedule.pb_ids),
       jnp.asarray(schedule.cb_ids), xp, w, bias.reshape(1, k))

@@ -158,6 +158,7 @@ def conv2d_wu(x, do, *, stride: int = 1, padding: int = 0,
             blocks=[(band, x.dtype), (do_tile, do.dtype),
                     (dw_tile, accum_dtype)]),
         interpret=interpret,
+        name="conv_wu",
     )(xp, dop)
     return out.astype(x.dtype)
 
@@ -190,5 +191,6 @@ def _conv2d_wu_whole(x, do, *, stride, padding, r, s, b_p, k_blk,
                                lambda ki, ni, pi: (0, 0, 0, ki)),
         out_shape=jax.ShapeDtypeStruct((r, s, c, k), accum_dtype),
         interpret=interpret,
+        name="conv_wu_whole",
     )(xp, do)
     return out.astype(x.dtype)

@@ -90,4 +90,5 @@ def matmul_fused(a, b, *, bias=None, act: str = "none", residual=None,
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="matmul_fused",
     )(*args)

@@ -63,6 +63,7 @@ def moe_gmm(tokens, weights, tile_eid, *, bm: int = 128, bn: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, f), tokens.dtype),
         interpret=interpret,
+        name="moe_gmm",
     )(tile_eid, tokens, weights)
 
 

@@ -52,4 +52,5 @@ def maxpool2d(x, *, window: int = 3, stride: int = 2, padding: int = 1,
                                lambda ni, ki, pi: (ni, pi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, p, q, c), x.dtype),
         interpret=interpret,
+        name="maxpool",
     )(xp)

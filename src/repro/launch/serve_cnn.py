@@ -31,6 +31,7 @@ import time
 import jax
 import numpy as np
 
+from repro import obs
 from repro.graph import GxM, inception_v3, resnet50
 from repro.graph.serving import CnnInferenceEngine, pick_bucket
 from repro.launch.compile_cache import enable_compile_cache
@@ -43,6 +44,15 @@ class ImageServer:
     ``submit`` enqueues one image and returns a request id; ``step`` serves
     one padded bucket off the queue head; ``run`` drains the queue.  Results
     map request id -> (top-1 class, top-1 logit).
+
+    ``step`` runs in host phases (``obs.SPANS``) inside ``serve.step``:
+    ``serve.take`` (queue pops), ``serve.stack``, ``serve.fetch`` (the
+    engine's call, in which its ``engine.pad`` / ``engine.put`` /
+    ``engine.run`` nest, then the wait for the device and the copy of the
+    logits, wherever a wrapper of the engine makes it) and ``serve.post``.
+    Each is a span while a trace is collected and always a counter
+    (``stats()["phases"]``), as is each request's ``serve.queue_wait``,
+    from submit to take.  ``serve_s`` runs from take to logits.
     """
 
     def __init__(self, engine: CnnInferenceEngine, *, clock=None):
@@ -66,23 +76,31 @@ class ImageServer:
         the number of requests served (0 when the queue is empty)."""
         if not self.queue:
             return 0
-        take = min(len(self.queue), max(self.engine.buckets))
-        reqs = [self.queue.popleft() for _ in range(take)]
-        images = np.stack([img for _, img, _ in reqs])
-        bucket = pick_bucket(take, self.engine.buckets)
         st = self._counters
-        t0 = self.clock()
-        logits = np.asarray(self.engine.infer(images))
-        t1 = self.clock()
-        st["serve_s"] += t1 - t0
-        for (rid, _, t_enq), row in zip(reqs, logits):
-            top1 = int(np.argmax(row))
-            self.results[rid] = (top1, float(row[top1]))
-            self.latencies_s.append(t1 - t_enq)
-        st["batches"] += 1
-        st["images"] += take
-        st["padded_lanes"] += bucket - take
-        st["by_bucket"][bucket] += 1
+        take = min(len(self.queue), max(self.engine.buckets))
+        bucket = pick_bucket(take, self.engine.buckets)
+        batch = st["batches"]
+        with obs.phase("serve.step", batch=batch, n=take, bucket=bucket):
+            with obs.phase("serve.take", batch=batch):
+                reqs = [self.queue.popleft() for _ in range(take)]
+                t0 = self.clock()
+                for _, _, t_enq in reqs:
+                    obs.add("serve.queue_wait", int((t0 - t_enq) * 1e9))
+            with obs.phase("serve.stack", batch=batch):
+                images = np.stack([img for _, img, _ in reqs])
+            with obs.phase("serve.fetch", batch=batch):
+                logits = np.asarray(self.engine.infer(images))
+            t1 = self.clock()
+            with obs.phase("serve.post", batch=batch):
+                st["serve_s"] += t1 - t0
+                for (rid, _, t_enq), row in zip(reqs, logits):
+                    top1 = int(np.argmax(row))
+                    self.results[rid] = (top1, float(row[top1]))
+                    self.latencies_s.append(t1 - t_enq)
+                st["batches"] += 1
+                st["images"] += take
+                st["padded_lanes"] += bucket - take
+                st["by_bucket"][bucket] += 1
         return take
 
     def run(self) -> dict[int, tuple[int, float]]:
@@ -93,9 +111,12 @@ class ImageServer:
     def stats(self) -> dict:
         """Counter snapshot plus the enqueue->complete latency summary
         (queue wait included — that is what a client experiences, not just
-        the executor's serve time)."""
+        the executor's serve time), and under ``phases`` the process's
+        phase counters (``obs.counters()``: count and host seconds of each
+        serving phase; take ``obs.since`` of two snapshots for a window)."""
         st = dict(self._counters)
         st["by_bucket"] = dict(st["by_bucket"])
+        st["phases"] = obs.counters()
         lat = np.sort(np.asarray(self.latencies_s, dtype=np.float64))
         st["latency"] = {
             "count": int(lat.size),

@@ -157,20 +157,25 @@ def make_cnn_train_step_dp(gxm, mesh, *, lr: float = 0.1,
         return div(loss), div(stats), div(grads)
 
     def dp_step(state, batch):
+        # named scopes: each phase's device ops carry its name in a trace
         params = state["params"]
-        loss, stats, grads = local_grads(params, batch)
+        with jax.named_scope("grads"):
+            loss, stats, grads = local_grads(params, batch)
         # the GxM reduction point: local dW exists (wu pass done), the
         # optimizer has not run — §II-J's compute/communication seam
-        if compress == "int8":
-            residual = jax.tree.map(lambda r: r[0], state["residual"])
-            grads, residual = compressed_psum_tree(grads, axis, residual)
-            new_residual = jax.tree.map(lambda r: r[None], residual)
-        else:
-            grads = jax.lax.pmean(grads, axis)
-        loss = jax.lax.pmean(loss, axis)
-        stats = jax.lax.pmean(stats, axis)
-        new_params = jax.tree.map(lambda p, g: p - lr * g, params, grads)
-        apply_bn_updates(new_params, stats, bn_momentum)
+        with jax.named_scope("grad_allreduce"):
+            if compress == "int8":
+                residual = jax.tree.map(lambda r: r[0], state["residual"])
+                grads, residual = compressed_psum_tree(grads, axis, residual)
+                new_residual = jax.tree.map(lambda r: r[None], residual)
+            else:
+                grads = jax.lax.pmean(grads, axis)
+            loss = jax.lax.pmean(loss, axis)
+        with jax.named_scope("bn_pmean"):
+            stats = jax.lax.pmean(stats, axis)
+        with jax.named_scope("sgd"):
+            new_params = jax.tree.map(lambda p, g: p - lr * g, params, grads)
+            apply_bn_updates(new_params, stats, bn_momentum)
         new_state = {"params": new_params, "step": state["step"] + 1}
         if compress == "int8":
             new_state["residual"] = new_residual
