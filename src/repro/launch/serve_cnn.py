@@ -47,9 +47,10 @@ class ImageServer:
 
     ``step`` runs in host phases (``obs.SPANS``) inside ``serve.step``:
     ``serve.take`` (queue pops), ``serve.stack``, ``serve.fetch`` (the
-    engine's call, in which its ``engine.pad`` / ``engine.put`` /
-    ``engine.run`` nest, then the wait for the device and the copy of the
-    logits, wherever a wrapper of the engine makes it) and ``serve.post``.
+    engine's call, in which its ``engine.pad`` and, per chunk of the batch
+    fed to the device, ``engine.chunk`` / ``engine.put`` / ``engine.run``
+    nest, then the wait for the device and the copy of the logits,
+    wherever a wrapper of the engine makes it) and ``serve.post``.
     Each is a span while a trace is collected and always a counter
     (``stats()["phases"]``), as is each request's ``serve.queue_wait``,
     from submit to take.  ``serve_s`` runs from take to logits.

@@ -30,11 +30,14 @@ try:                                    # the profiler's session, if any
 except ImportError:                     # pragma: no cover
     _SESSION = None
 
-# host spans of the serving path: ``serve.step`` encloses the others, and
-# ``serve.fetch`` the ``engine.*`` three; each is also a counter, as is
-# ``serve.queue_wait`` (a request's submit to take)
+# host spans of the serving path: ``serve.step`` encloses the others,
+# ``serve.fetch`` the ``engine.*`` ones, and ``engine.chunk`` (once per
+# chunk of the batch fed to the device) that chunk's ``engine.put`` and
+# ``engine.run``; each is also a counter, as is ``serve.queue_wait`` (a
+# request's submit to take)
 SPANS = ("serve.step", "serve.take", "serve.stack", "engine.pad",
-         "engine.put", "engine.run", "serve.fetch", "serve.post")
+         "engine.chunk", "engine.put", "engine.run", "serve.fetch",
+         "serve.post")
 
 # the conv passes: each names its kernels and the scope of its XLA glue
 CONV_FWD, CONV_BWD_DATA, CONV_WU = "conv_fwd", "conv_bwd_data", "conv_wu"

@@ -116,7 +116,10 @@ def test_server_phases_counted_once_per_step(monkeypatch):
     assert st["batches"] == 2 and len(server.results) == 7
     for name in obs.SPANS:
         assert got[name]["count"] == 2, name
-    engine = sum(got[n]["s"] for n in obs.SPANS if n.startswith("engine."))
+    # engine.chunk encloses its chunk's engine.put and engine.run
+    assert got["engine.put"]["s"] + got["engine.run"]["s"] \
+        <= got["engine.chunk"]["s"]
+    engine = got["engine.pad"]["s"] + got["engine.chunk"]["s"]
     assert 0 < engine <= got["serve.fetch"]["s"]
     step = sum(got[f"serve.{n}"]["s"] for n in ("take", "stack", "fetch",
                                                 "post"))

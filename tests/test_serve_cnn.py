@@ -7,9 +7,12 @@ import pytest
 
 from repro import backend as be
 from repro.graph import GxM, resnet50
+from repro import obs
+from repro.graph import serving
 from repro.graph.serving import (CnnInferenceEngine, cnn_model_flops,
                                  conv_shapes, distinct_conv_signatures,
-                                 make_buckets, pick_bucket, round_buckets)
+                                 feed_chunk, make_buckets, pick_bucket,
+                                 round_buckets)
 from repro.launch.mesh import make_host_mesh
 from repro.launch.serve_cnn import ImageServer
 from repro.tune.cache import TuneCache, conv_key
@@ -165,6 +168,91 @@ def test_warmup_compiles_every_bucket(rng):
     assert set(report["compile_s"]) == {2, 4}
     for b in (2, 4):
         assert eng.aot_executable(b) is eng._compiled[b]
+
+
+# -- pipelined host-to-device feed -------------------------------------------
+
+F32_224 = 224 * 224 * 3 * 4           # bytes of one 224x224x3 f32 image
+
+
+@pytest.mark.parametrize("bucket,image_bytes,shards,want", [
+    (128, F32_224, 1, 16),            # the offline bucket: 8 chunks of 16
+    (128, F32_224, 4, 16),
+    (64, F32_224, 1, 16),             # the server ladder's largest rung
+    (16, F32_224, 1, 16),             # fits already: one chunk
+    (16, F32_224, 4, 16),
+    (128, F32_224 // 2, 1, 32),       # bf16 images
+    (64, 448 * 448 * 3 * 4, 1, 4),    # 448x448 f32
+    (120, F32_224, 1, 15),            # largest divisor under the bytes
+    (120, F32_224, 4, 12),            # ... that is a multiple of the shards
+    (8, 3 << 20, 8, 8),               # no divisor is a shard multiple
+    (4, 25 << 20, 1, 4),              # one image is over the bytes
+])
+def test_feed_chunk_plan(bucket, image_bytes, shards, want):
+    c = feed_chunk(bucket, image_bytes, shards)
+    assert c == want
+    assert bucket % c == 0 and c % shards == 0
+    assert c == bucket or c * image_bytes <= serving.FEED_CHUNK_BYTES
+
+
+def _chunked_engine(monkeypatch, m, params):
+    """Bucket 8 of 32x32 f32 images fed as 4 chunks of 2; bucket 2 whole."""
+    monkeypatch.setattr(serving, "FEED_CHUNK_BYTES", 2 * 32 * 32 * 3 * 4)
+    eng = _engine(m, params, mesh=make_host_mesh(data=1), buckets=(2, 8))
+    assert eng.chunks == {2: 2, 8: 2}
+    eng.warmup(autotune="off")
+    return eng
+
+
+def test_chunked_feed_matches_each_chunks_forward(monkeypatch, rng):
+    """n=5 in bucket 8: chunks [0:2], [2:4] and [4:5] with the only padded
+    lane; a chunk of zeros past the last image is not sent.  Each chunk's
+    logits are the model's forward on that chunk's images, and the result
+    matches the unchunked engine up to XLA-CPU's batch-size-dependent
+    sums."""
+    m, params = _tiny()
+    eng = _chunked_engine(monkeypatch, m, params)
+    assert eng.aot_executable(8) is eng._compiled[2]
+    assert eng.aot_executable(8) is eng.aot_executable(2)
+    assert 8 not in eng._compiled
+    assert set(eng._joins) == {(2, 2), (2, 3), (2, 4)}   # warmed joins
+    x = rng.standard_normal((5, 32, 32, 3)).astype(np.float32)
+    sent = []
+    real_put = jnp.asarray
+    monkeypatch.setattr(serving.jnp, "asarray",
+                        lambda a: (sent.append(np.array(a)), real_put(a))[1])
+    got = np.asarray(eng.infer(x))
+    monkeypatch.setattr(serving.jnp, "asarray", real_put)
+    assert got.shape == (5, 10)
+    assert [s.shape[0] for s in sent] == [2, 2, 2]
+    for s in sent[:-1]:
+        assert np.all(np.any(s != 0, axis=(1, 2, 3)))
+    assert np.all(sent[-1][1] == 0) and np.any(sent[-1][0] != 0)
+    fwd = m.make_infer(mesh=None, donate_input=False)
+    want = np.concatenate([np.asarray(fwd(params, jnp.asarray(s)))
+                           for s in sent])[:5]
+    np.testing.assert_array_equal(got, want)
+    monkeypatch.setattr(serving, "FEED_CHUNK_BYTES", 1 << 30)
+    one = _engine(m, params, mesh=make_host_mesh(data=1), buckets=(8,))
+    assert one.chunks == {8: 8}
+    np.testing.assert_allclose(got, np.asarray(one.infer(x)), rtol=1e-5,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("n,chunks", [(8, 4), (7, 4), (5, 3), (2, 1), (1, 1)])
+def test_chunk_counter_per_call(monkeypatch, rng, n, chunks):
+    """``engine.chunk`` is entered once per chunk sent: 4 for a full bucket
+    8, fewer for a partial one, 1 on the unchunked bucket 2; put and run
+    once per chunk, pad once per call."""
+    m, params = _tiny()
+    eng = _chunked_engine(monkeypatch, m, params)
+    x = rng.standard_normal((n, 32, 32, 3)).astype(np.float32)
+    before = obs.counters()
+    jax.block_until_ready(eng.infer(x))
+    got = obs.since(before, obs.counters())
+    for name in ("engine.chunk", "engine.put", "engine.run"):
+        assert got[name]["count"] == chunks, name
+    assert got["engine.pad"]["count"] == 1
 
 
 # -- quantized serving (§II-K end to end) ------------------------------------
